@@ -55,6 +55,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 import numpy as np
 
+from repro.launch.cli import cpu_rehearsal_env, enable_compile_cache
+
+
 #: default fairness probe: two tenants at 2:1 — the ratio the bench gate
 #: (tools/check_bench.py, FAIRNESS_TOLERANCE) checks the goodput against
 DEFAULT_TENANTS = ({"name": "gold", "weight": 2.0},
@@ -360,8 +363,7 @@ def main() -> None:
                     help="emit the raw result dicts as JSON")
     args = ap.parse_args()
     if args.banks:
-        flag = f"--xla_force_host_platform_device_count={args.banks}"
-        env = dict(os.environ, XLA_FLAGS=flag)
+        env = cpu_rehearsal_env(args.banks)
         cmd = [sys.executable, "-m", "benchmarks.loadgen",
                *(a for a in sys.argv[1:]
                  if not a.startswith("--banks")
@@ -406,4 +408,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -30,6 +30,8 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", "src"))
 
+from repro.launch.cli import cpu_rehearsal_env, enable_compile_cache  # noqa: E402
+
 
 def emit(rows) -> None:
     if not rows:
@@ -97,9 +99,7 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.banks:
-        env = dict(os.environ,
-                   XLA_FLAGS="--xla_force_host_platform_device_count="
-                             f"{args.banks}")
+        env = cpu_rehearsal_env(args.banks)
         cmd = [sys.executable, "-m", "benchmarks.run", "--suite", args.suite]
         if args.full:
             cmd.append("--full")
@@ -120,4 +120,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

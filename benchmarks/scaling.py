@@ -34,6 +34,9 @@ sys.path.insert(
 
 import numpy as np
 
+from repro.launch.cli import cpu_rehearsal_env, enable_compile_cache
+
+
 #: Workloads whose weak scaling a *host-simulated* backend can sustain —
 #: transfer/dispatch-dominated ones.  On real PIM hardware every PrIM
 #: workload weak-scales with ranks (paper §5: each rank brings its own
@@ -174,8 +177,7 @@ def main() -> None:
     )
     args = ap.parse_args()
     if args.devices:
-        flag = f"--xla_force_host_platform_device_count={args.devices}"
-        env = dict(os.environ, XLA_FLAGS=flag)
+        env = cpu_rehearsal_env(args.devices)
         cmd = [
             sys.executable,
             "-m",
@@ -215,4 +217,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

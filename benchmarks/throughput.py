@@ -37,6 +37,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 import numpy as np
 
+from repro.launch.cli import cpu_rehearsal_env, enable_compile_cache
+
 
 def _sched_run(grid, entry, args_list, *, n_chunks, plan=None,
                serialized_per_req=0.0):
@@ -172,8 +174,7 @@ def main() -> None:
                     help="subset of registry names (default: full registry)")
     args = ap.parse_args()
     if args.banks:
-        env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_"
-                                         f"count={args.banks}")
+        env = cpu_rehearsal_env(args.banks)
         cmd = [sys.executable, "-m", "benchmarks.throughput",
                "--requests", str(args.requests), "--chunks", str(args.chunks),
                "--scale", str(args.scale)]
@@ -198,4 +199,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.launch import serve as serve_mod
+from repro.launch.cli import cpu_rehearsal_env, enable_compile_cache
 from repro.models import transformer
 from repro.pim.decode import DecodeEngine
 from repro.runtime.elastic import carve_mesh
@@ -63,6 +64,7 @@ def main(args):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="tinyllama-1.1b",
                     help="arch id for the smoke config base")
@@ -77,8 +79,7 @@ if __name__ == "__main__":
     ap.add_argument("--max-new", type=int, default=20)
     args = ap.parse_args()
     if args.banks:
-        env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_"
-                                         f"count={args.banks}")
+        env = cpu_rehearsal_env(args.banks)
         cmd = [sys.executable, os.path.abspath(__file__)]
         for flag in ("model", "ranks", "streams", "layers", "prompt-len",
                      "max-new"):

@@ -21,6 +21,8 @@ import sys
 
 import numpy as np
 
+from repro.launch.cli import cpu_rehearsal_env, enable_compile_cache
+
 
 def main(autotune: bool = True):
     from repro import pim
@@ -79,6 +81,7 @@ def main(autotune: bool = True):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--banks", type=int, default=0,
                     help="re-exec with N forced host devices")
@@ -86,8 +89,7 @@ if __name__ == "__main__":
                     help="skip calibration; serve with the untuned defaults")
     args = ap.parse_args()
     if args.banks:
-        env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_"
-                                         f"count={args.banks}")
+        env = cpu_rehearsal_env(args.banks)
         cmd = [sys.executable, os.path.abspath(__file__)]
         if args.no_autotune:
             cmd.append("--no-autotune")

@@ -36,7 +36,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import hlo
-from .compat import shard_map
 
 AXIS = "banks"
 RANK_AXIS = "ranks"
@@ -134,11 +133,13 @@ class BankGrid:
         """Run ``fn`` independently on every bank (DPU kernel launch).
 
         Default specs shard the leading axis across banks. With ``check=True``
-        the lowered phase is asserted collective-free (DPUs cannot talk)."""
+        the lowered phase is asserted collective-free (DPUs cannot talk).
+        The phase is jitted: one compiled launch per call, where an eager
+        ``shard_map`` would compile and dispatch every operation apart."""
         ispec = in_specs if in_specs is not None else P(AXIS)
         ospec = out_specs if out_specs is not None else P(AXIS)
-        mapped = shard_map(fn, mesh=self.mesh, in_specs=ispec,
-                           out_specs=ospec)
+        mapped = jax.jit(jax.shard_map(fn, mesh=self.mesh, in_specs=ispec,
+                                       out_specs=ospec, check_vma=False))
         if not check:
             return mapped
 

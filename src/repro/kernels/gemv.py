@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 
 def _gemv_kernel(a_ref, x_ref, o_ref, acc_ref, *, nn):
     j = pl.program_id(1)
@@ -53,7 +51,7 @@ def gemv(a, x, *, block_m: int = 128, block_n: int = 512,
         out_specs=pl.BlockSpec((block_m, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, x2)

@@ -13,8 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.core.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _hist_kernel(x_ref, o_ref, *, nbins):
@@ -44,7 +43,7 @@ def histogram(values, nbins: int, *, block: int = 4096,
         in_specs=[pl.BlockSpec((1, block), lambda i: (0, i))],
         out_specs=pl.BlockSpec((1, nbins), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, nbins), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(values.reshape(1, n))

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 
 def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref, *,
                 L, nchunks):
@@ -87,7 +85,7 @@ def ssd_scan(x, a, b, c, *, chunk: int = 128, interpret: bool = False):
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, a, b, c)

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 
 def _gmm_kernel(cnt_ref, x_ref, w_ref, o_ref, acc_ref, *, nd, bc):
     i = pl.program_id(1)
@@ -62,7 +60,7 @@ def moe_gmm(xg, w, counts, *, block_c: int = 128, block_f: int = 512,
                                lambda e, i, j, kd: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, C, f), xg.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -25,21 +25,30 @@ from . import ref
 from . import scan as _scan
 from . import spmv as _spmv
 
-_BACKEND = "interpret" if jax.default_backend() == "cpu" else "pallas"
+#: resolved on first use, never at import: asking JAX for its backend
+#: initialises the device, and on a TPU host that claims the chip
+_BACKEND: str | None = None
 
 
 def set_backend(name: str) -> None:
     global _BACKEND
     assert name in ("pallas", "interpret", "ref")
+    if name == "interpret" and jax.default_backend() == "tpu":
+        raise ValueError("interpret mode is for CPU validation; a TPU runs "
+                         "the compiled kernels")
     _BACKEND = name
 
 
 def get_backend() -> str:
+    global _BACKEND
+    if _BACKEND is None:
+        _BACKEND = "interpret" if jax.default_backend() == "cpu" \
+            else "pallas"
     return _BACKEND
 
 
 def _interp() -> bool:
-    return _BACKEND == "interpret"
+    return get_backend() == "interpret"
 
 
 def _pad_to(x, mult: int, axis: int):
@@ -58,7 +67,7 @@ def _pad_to(x, mult: int, axis: int):
 def attention(q, k, v, *, causal: bool = True, window: int | None = None,
               block_q: int = 128, block_k: int = 128):
     """GQA flash attention; q (B,H,S,D), k/v (B,KVH,T,D), any S/T/D."""
-    if _BACKEND == "ref":
+    if get_backend() == "ref":
         return ref.attention(q, k, v, causal=causal, window=window)
     B, H, S, D = q.shape
     T = k.shape[2]
@@ -88,7 +97,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window=None,
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n"))
 def gemv(a, x, *, block_m: int = 128, block_n: int = 512):
-    if _BACKEND == "ref":
+    if get_backend() == "ref":
         return ref.gemv(a, x)
     m, n = a.shape
     bm = min(block_m, max(8, 1 << (m - 1).bit_length()))
@@ -101,21 +110,27 @@ def gemv(a, x, *, block_m: int = 128, block_n: int = 512):
 
 # -- reduce / scan -------------------------------------------------------------
 
+def _block_1d(n: int, block: int) -> int:
+    """Block of a 1-D reduce/scan: the smaller of ``block`` and the power of
+    two at or above ``n``, but never under ``MIN_BLOCK`` (whole (rows, 128)
+    tiles; the zero padding is sum- and scan-safe)."""
+    return max(_red.MIN_BLOCK, min(block, 1 << (n - 1).bit_length()))
+
+
 @functools.partial(jax.jit, static_argnames=("block",))
 def reduce_sum(x, *, block: int = 4096):
-    if _BACKEND == "ref":
+    if get_backend() == "ref":
         return ref.reduce_sum(x)
-    n = x.shape[0]
-    b = min(block, max(128, 1 << (n - 1).bit_length()))
+    b = _block_1d(x.shape[0], block)
     return _red.reduce_sum(_pad_to(x, b, 0), block=b, interpret=_interp())
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
 def scan_inclusive(x, *, block: int = 4096):
-    if _BACKEND == "ref":
+    if get_backend() == "ref":
         return ref.scan_inclusive(x)
     n = x.shape[0]
-    b = min(block, max(128, 1 << (n - 1).bit_length()))
+    b = _block_1d(n, block)
     return _scan.scan_inclusive(_pad_to(x, b, 0), block=b,
                                 interpret=_interp())[:n]
 
@@ -129,7 +144,7 @@ def scan_exclusive(x, *, block: int = 4096):
 
 @functools.partial(jax.jit, static_argnames=("nbins", "block"))
 def histogram(values, nbins: int, *, block: int = 4096):
-    if _BACKEND == "ref":
+    if get_backend() == "ref":
         return ref.histogram(values, nbins)
     n = values.shape[0]
     b = min(block, max(128, 1 << (n - 1).bit_length()))
@@ -143,7 +158,7 @@ def histogram(values, nbins: int, *, block: int = 4096):
 
 @functools.partial(jax.jit, static_argnames=("block_rows",))
 def spmv_ell(vals, cols, x, *, block_rows: int = 128):
-    if _BACKEND == "ref":
+    if get_backend() == "ref":
         return ref.spmv_ell(vals, cols, x)
     rows = vals.shape[0]
     br = min(block_rows, max(8, 1 << (rows - 1).bit_length()))
@@ -158,7 +173,7 @@ def spmv_ell(vals, cols, x, *, block_rows: int = 128):
 @functools.partial(jax.jit, static_argnames=("block_c", "block_f", "block_d"))
 def moe_gmm(xg, w, counts, *, block_c: int = 128, block_f: int = 512,
             block_d: int = 512):
-    if _BACKEND == "ref":
+    if get_backend() == "ref":
         return ref.moe_gmm(xg, w, counts)
     E, C, d = xg.shape
     f = w.shape[-1]
@@ -176,7 +191,7 @@ def moe_gmm(xg, w, counts, *, block_c: int = 128, block_f: int = 512,
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def ssd_scan(x, a, b, c, *, chunk: int = 128):
-    if _BACKEND == "ref":
+    if get_backend() == "ref":
         return ref.ssd_scan(x, a, b, c)
     B, S, H, P = x.shape
     N = b.shape[-1]

@@ -1,8 +1,11 @@
 """Reduction Pallas kernel (PrIM §4.12 RED).
 
 The PrIM version does per-tasklet local sums then a tree merge; on TPU the
-grid is sequential, so the "tree" collapses into a carried VMEM accumulator —
-the final block writes the scalar.  Mirrors the paper's finding that the
+grid is sequential, so the "tree" collapses into one carried accumulator.
+The accumulator is a vector-shaped ``(8, 128)`` output block that every grid
+step revisits (the TPU stores vectors, not scalars, to VMEM): each block of
+``(rows, 128)`` folds into it with elementwise adds, and the 1024 partials
+are summed after the kernel.  Mirrors the paper's finding that the
 single-accumulator variant beats tree variants when merge cost dominates.
 """
 from __future__ import annotations
@@ -14,38 +17,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
+LANES, SUBLANES = 128, 8
+#: smallest block (elements): whole (8, 128) tiles of 32-bit values, with
+#: room for the (16, 128) / (32, 128) tiles of 16- and 8-bit inputs
+MIN_BLOCK = 32 * LANES
 
 
-def _reduce_kernel(x_ref, o_ref, acc_ref, *, nb):
-    i = pl.program_id(0)
+def acc_dtype(dtype):
+    """Accumulator dtype: float32 for floats, the input's own for ints."""
+    return jnp.float32 if jnp.issubdtype(dtype, jnp.floating) else dtype
 
-    @pl.when(i == 0)
+
+def _reduce_kernel(x_ref, o_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    acc_ref[0, 0] += jnp.sum(x_ref[...].astype(acc_ref.dtype))
-
-    @pl.when(i == nb - 1)
-    def _done():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+    x = x_ref[...].astype(o_ref.dtype)              # (rows, 128)
+    o_ref[...] += x.reshape(-1, SUBLANES, LANES).sum(axis=0)
 
 
 def reduce_sum(x, *, block: int = 4096, interpret: bool = False):
-    """Sum of a 1-D array; len(x) % block == 0 (ops.py pads)."""
+    """Sum of a 1-D array; len(x) % block == 0 and block % MIN_BLOCK == 0
+    (ops.py pads).  Floats accumulate in float32 and return it."""
     (n,) = x.shape
-    assert n % block == 0
-    nb = n // block
-    acc_dtype = jnp.float32 if jnp.issubdtype(x.dtype, jnp.floating) else x.dtype
-    out = pl.pallas_call(
-        functools.partial(_reduce_kernel, nb=nb),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), x.dtype),
-        scratch_shapes=[pltpu.VMEM((1, 1), acc_dtype)],
-        compiler_params=tpu_compiler_params(
+    assert n % block == 0 and block % MIN_BLOCK == 0, (n, block)
+    rows = block // LANES
+    acc = acc_dtype(x.dtype)
+    partials = pl.pallas_call(
+        _reduce_kernel,
+        grid=(n // block,),
+        in_specs=[pl.BlockSpec((rows, LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((SUBLANES, LANES), acc),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(x.reshape(1, n))
-    return out[0, 0]
+    )(x.reshape(n // LANES, LANES))
+    return jnp.sum(partials, dtype=acc)

@@ -2,10 +2,16 @@
 
 The paper's Reduce-Scan-Scan decomposes the array into per-DPU chunks: local
 reduce → host scans the per-chunk totals → local scan + offset.  On TPU the
-sequential grid makes the middle step a carried scalar: each block writes
-``carry + cumsum(block)`` and bumps the carry by the block total — a single
+sequential grid makes the middle step a carried total: each block writes
+``carry + scan(block)`` and bumps the carry by the block total — a single
 pass instead of the paper's 3·N+1 accesses (recorded as a beyond-paper win in
 EXPERIMENTS.md §Perf for the SCAN benchmark).
+
+A block is a ``(rows, 128)`` tile.  The in-block scan is two log-step
+shift-and-add passes (Hillis-Steele), built from lane/sublane rolls and
+masks that the TPU lowers natively: first along the 128 lanes of every row,
+then over the row totals down the sublanes.  The carry is kept as a vector
+block, every element holding the running total.
 """
 from __future__ import annotations
 
@@ -14,40 +20,53 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
+from .reduce import LANES, MIN_BLOCK, SUBLANES, acc_dtype
+
+
+def _shift_add_scan(y, axis: int):
+    """Inclusive scan of ``y`` along ``axis`` in log2(len) roll+add steps."""
+    n = y.shape[axis]
+    idx = jax.lax.broadcasted_iota(jnp.int32, y.shape, axis)
+    k = 1
+    while k < n:
+        y = y + jnp.where(idx >= k, pltpu.roll(y, k, axis), 0).astype(y.dtype)
+        k *= 2
+    return y
 
 
 def _scan_kernel(x_ref, o_ref, carry_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    x = x_ref[...].astype(carry_ref.dtype)         # (1, block)
-    local = jnp.cumsum(x, axis=-1)
-    o_ref[...] = (carry_ref[0, 0] + local).astype(o_ref.dtype)
-    carry_ref[0, 0] += jnp.sum(x)
+    rows = x_ref.shape[0]
+    y = _shift_add_scan(x_ref[...].astype(carry_ref.dtype), 1)  # per row
+    row_tot = jnp.broadcast_to(y[:, LANES - 1:], y.shape)
+    incl = _shift_add_scan(row_tot, 0)              # running row totals
+    carry = carry_ref[0:1, :]
+    o_ref[...] = (y + (incl - row_tot) + carry).astype(o_ref.dtype)
+    carry_ref[...] = jnp.broadcast_to(carry + incl[rows - 1:, :],
+                                      carry_ref.shape)
 
 
 def scan_inclusive(x, *, block: int = 4096, interpret: bool = False):
-    """Inclusive prefix sum of a 1-D array; len(x) % block == 0 (ops.py pads)."""
+    """Inclusive prefix sum of a 1-D array; len(x) % block == 0 and
+    block % MIN_BLOCK == 0 (ops.py pads)."""
     (n,) = x.shape
-    assert n % block == 0
-    nb = n // block
-    acc_dtype = jnp.float32 if jnp.issubdtype(x.dtype, jnp.floating) else x.dtype
+    assert n % block == 0 and block % MIN_BLOCK == 0, (n, block)
+    rows = block // LANES
     out = pl.pallas_call(
         _scan_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, n), x.dtype),
-        scratch_shapes=[pltpu.VMEM((1, 1), acc_dtype)],
-        compiler_params=tpu_compiler_params(
+        grid=(n // block,),
+        in_specs=[pl.BlockSpec((rows, LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n // LANES, LANES), x.dtype),
+        scratch_shapes=[pltpu.VMEM((SUBLANES, LANES), acc_dtype(x.dtype))],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(x.reshape(1, n))
-    return out[0]
+    )(x.reshape(n // LANES, LANES))
+    return out.reshape(n)
 
 
 def scan_exclusive(x, **kw):

@@ -12,8 +12,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.core.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _spmv_kernel(vals_ref, cols_ref, x_ref, o_ref):
@@ -41,7 +40,7 @@ def spmv_ell(vals, cols, x, *, block_rows: int = 128,
         ],
         out_specs=pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, 1), vals.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(vals, cols, x.reshape(1, n))

@@ -31,7 +31,6 @@ from repro import optim
 from repro.configs import (ARCHS, SHAPES, get_config, input_specs,
                            skip_reason)
 from repro.core import hlo as hlo_mod
-from repro.core.compat import set_mesh
 from repro.core import perfmodel as perf_mod
 from repro.core.perfmodel import (RooflineTerms, model_flops_decode,
                                   model_flops_train)
@@ -100,7 +99,7 @@ def lower_cell(cfg, shape, mesh, *, opt_flags=()):
     _, pspecs = apply_opt_flags(cfg, pspecs, opt_flags)
     bspecs_tree = input_specs(cfg, shape)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             oshapes = jax.eval_shape(optim.init, pshapes)
             ocfg = optim.AdamWConfig()

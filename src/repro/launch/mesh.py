@@ -12,7 +12,10 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the shardings are given explicitly at jit boundaries
+    # (jax.make_mesh defaults to Explicit axes, sharding-in-types)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> tuple[str, ...] | str:

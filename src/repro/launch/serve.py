@@ -101,7 +101,10 @@ def make_serve_step(cfg: ModelConfig, mesh, param_specs, cache_specs_tree,
 
 def greedy_generate(params, cfg: ModelConfig, mesh, param_specs, prompt,
                     max_new: int, frontend=None):
-    """Simple batched greedy decoding driver (examples/serve_decode.py)."""
+    """Simple batched greedy decoding driver — the reference the PIM
+    decode engine is checked against token for token.  Matmuls run at
+    "highest" precision, as the engine's do: on a TPU the default computes
+    a float32 matmul in bfloat16 passes."""
     B, S = prompt.shape
     cache, cspecs = make_cache(params, cfg, mesh, B, S + max_new,
                                frontend=frontend)
@@ -110,12 +113,14 @@ def greedy_generate(params, cfg: ModelConfig, mesh, param_specs, prompt,
     # prefill token-by-token (simple; a fused prefill is the perf path)
     tok = prompt[:, :1]
     out = [tok]
-    for i in range(S + max_new - 1):
-        logits, cache = step(params, cache, tok, None,
-                             frontend if cfg.family == "vlm" else None)
-        if i + 1 < S:
-            tok = prompt[:, i + 1:i + 2]
-        else:
-            tok = jnp.argmax(logits[:, -1:, :], axis=-1).astype(jnp.int32)
-        out.append(tok)
+    with jax.default_matmul_precision("highest"):
+        for i in range(S + max_new - 1):
+            logits, cache = step(params, cache, tok, None,
+                                 frontend if cfg.family == "vlm" else None)
+            if i + 1 < S:
+                tok = prompt[:, i + 1:i + 2]
+            else:
+                tok = jnp.argmax(logits[:, -1:, :],
+                                 axis=-1).astype(jnp.int32)
+            out.append(tok)
     return jnp.concatenate(out, axis=1)

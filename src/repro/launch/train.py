@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import optim
-from repro.core.compat import shard_map
 from repro.models import transformer
 from repro.models.layers import ModelConfig
 from repro.runtime.elastic import shardings_for
@@ -121,10 +120,10 @@ def _compressed_dp_grads(g, mesh):
         raise ValueError("compress_grads requires model axis of size 1")
     dp = data_axes(mesh)
     axis = dp if isinstance(dp, str) else dp[-1]
-    f = shard_map(
+    f = jax.shard_map(
         lambda t: optim.psum_compressed(
             jax.tree.map(lambda x: x / mesh.shape[axis], t), axis),
-        mesh=mesh, in_specs=P(), out_specs=P())
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
     return f(g)
 
 

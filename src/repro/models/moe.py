@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import get_abstract_mesh, shard_map
 from repro.kernels import ops
 from .layers import ModelConfig, dense_init, emb_axis
 
@@ -139,7 +138,7 @@ def apply_ep(p, cfg: ModelConfig, x, *, model_axis: str = "model"):
     """
     B, S, d = x.shape
     E, K = cfg.moe_experts, cfg.moe_top_k
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     dp = tuple(a for a in mesh.axis_names if a != model_axis)
 
     def local(xt, router, wi, wo):
@@ -188,11 +187,11 @@ def apply_ep(p, cfg: ModelConfig, x, *, model_axis: str = "model"):
         aux = jax.lax.pmean(aux, dp) if dp else aux
         return y.astype(xt.dtype), aux
     fs = "data" if cfg.fsdp else None
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, None), P(fs, None),
                   P(model_axis, fs, None), P(model_axis, None, fs)),
-        out_specs=(P(dp, None), P()))
+        out_specs=(P(dp, None), P()), check_vma=False)
     xt = x.reshape(B * S, d)
     y, aux = mapped(xt, p["router"], p["wi"], p["wo"])
 

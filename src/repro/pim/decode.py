@@ -276,11 +276,15 @@ class DecodeEngine:
         streams = [_Stream(f"stream-{b}", self.cfg, S + max_new,
                            prompts[b, 0]) for b in range(B)]
         toks = prompts[:, 0]
-        for i in range(S + max_new - 1):
-            nxt = self._step(streams, toks, step=i, generated=i + 1 >= S)
-            toks = prompts[:, i + 1] if i + 1 < S else nxt
-            for s, t in zip(streams, toks):
-                s.tokens.append(int(t))
+        # host math at the GEMV phases' full float32 (prim.common.PRECISION)
+        # and the reference's: a TPU's default runs bfloat16 passes
+        with jax.default_matmul_precision("highest"):
+            for i in range(S + max_new - 1):
+                nxt = self._step(streams, toks, step=i,
+                                 generated=i + 1 >= S)
+                toks = prompts[:, i + 1] if i + 1 < S else nxt
+                for s, t in zip(streams, toks):
+                    s.tokens.append(int(t))
         return np.asarray([s.tokens for s in streams], np.int32)
 
     def report(self) -> dict:

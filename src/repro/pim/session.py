@@ -92,6 +92,23 @@ def session(banks: int | None = None, *, ranks: int | None = None,
                       **kwargs)
 
 
+def resident_budget(grid: BankGrid, headroom_per_bank: int) -> int:
+    """Default residency budget of ``grid`` in bytes (DESIGN.md §12).
+
+    Where every bank's device reports ``memory_stats()`` (a TPU's HBM), the
+    budget is what each bank has free when the session opens —
+    ``bytes_limit`` minus ``bytes_in_use`` — less ``headroom_per_bank``
+    for the chunks in flight, summed over the banks.  A device that reports
+    nothing (the CPU backend) keeps the modelled DPU MRAM capacity
+    (:func:`~repro.core.perfmodel.mram_capacity_bytes`).
+    """
+    stats = [d.memory_stats() for d in grid.mesh.devices.flat]
+    if not all(s and "bytes_limit" in s for s in stats):
+        return mram_capacity_bytes(grid.n_banks)
+    return sum(max(0, s["bytes_limit"] - s.get("bytes_in_use", 0)
+                   - headroom_per_bank) for s in stats)
+
+
 def registry() -> Mapping[str, "WorkloadEntry"]:
     """The session-level workload registry view: name -> WorkloadEntry
     (lazy — importing the registry pulls the whole PrIM suite)."""
@@ -149,13 +166,15 @@ class PimSession:
             self._tuning, plans = plans, plans.plans
         telemetry = telemetry if telemetry is not None else Telemetry()
         # resident-operand cache (DESIGN.md §12): on by default, budgeted
-        # against the per-bank MRAM capacity model; an int is an explicit
-        # byte budget (resident=False disables — every request re-scatters)
+        # from the banks' device memory (the MRAM capacity model where the
+        # device reports none); an int is an explicit byte budget
+        # (resident=False disables — every request re-scatters)
         if isinstance(resident, ResidentCache):
             cache = resident
         elif resident:
             budget = (resident if not isinstance(resident, bool)
-                      else mram_capacity_bytes(self._grid.n_banks))
+                      else resident_budget(self._grid,
+                                           2 * max_batch_bytes))
             cache = ResidentCache(budget, metrics=telemetry.metrics)
         else:
             cache = None

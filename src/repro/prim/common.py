@@ -18,7 +18,20 @@ import time
 from typing import Callable
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+
+#: Matmul precision of every compute phase: full float32.  A TPU runs a
+#: float32 matmul at DEFAULT precision as bfloat16 passes, which misses the
+#: registry's float tolerance and decode's token parity; the CPU computes
+#: float32 exactly either way.
+PRECISION = jax.lax.Precision.HIGHEST
+
+
+def matvec(a, x):
+    """``a @ x`` at :data:`PRECISION` — the one matmul of the compute
+    phases (GEMV, GEMV-B/G, MLP)."""
+    return jnp.matmul(a, x, precision=PRECISION)
 
 
 @dataclasses.dataclass

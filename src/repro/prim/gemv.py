@@ -16,7 +16,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core import transfer as tx
 from repro.core.banked import AXIS, BankGrid
 from repro.kernels import ops
-from .common import ChunkedWorkload, PhaseTimer, pad_chunks, register_chunked, sync
+from .common import (ChunkedWorkload, PhaseTimer, matvec, pad_chunks,
+                     register_chunked, sync)
 
 
 def ref(a: np.ndarray, x: np.ndarray) -> np.ndarray:
@@ -33,7 +34,7 @@ def pim(grid: BankGrid, a: np.ndarray, x: np.ndarray, use_kernel: bool = False):
     def local(ab, xb):
         if use_kernel:
             return ops.gemv(ab[0], xb)[None]
-        return ab @ xb
+        return matvec(ab, xb)
 
     f = grid.bank_local(local, in_specs=(P(AXIS), P()))
     with t.phase("dpu"):
@@ -49,8 +50,7 @@ def pim(grid: BankGrid, a: np.ndarray, x: np.ndarray, use_kernel: bool = False):
 
 @functools.cache
 def _local(grid: BankGrid):
-    return jax.jit(grid.bank_local(lambda ab, xb: ab @ xb,
-                                   in_specs=(P(AXIS), P())))
+    return jax.jit(grid.bank_local(matvec, in_specs=(P(AXIS), P())))
 
 
 # The matrix is the residency candidate (DESIGN.md §12): its row chunks are

@@ -33,12 +33,21 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import transfer as tx
 from repro.core.banked import AXIS, BankGrid
-from .common import ChunkedWorkload, PhaseTimer, pad_chunks, register_chunked, sync
+from .common import (ChunkedWorkload, PhaseTimer, matvec, pad_chunks,
+                     register_chunked, sync)
 
 
 def _silu_f32(g):
     """silu in float32, cast back — the swiglu gate's exact numerics."""
     return jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype)
+
+
+def _bias_mv(wb, bb, xb):
+    return matvec(wb, xb) + bb
+
+
+def _gated_mv(gb, ub, xb):
+    return _silu_f32(matvec(gb, xb)) * matvec(ub, xb)
 
 
 # -- GEMV-B: y = W @ x + b ----------------------------------------------------
@@ -55,7 +64,7 @@ def pim_b(grid: BankGrid, w: dict, x: np.ndarray):
         dw = sync(grid.to_banks(wc))
         db = sync(grid.to_banks(bc))
         dx = sync(grid.broadcast(np.asarray(x)))
-    f = grid.bank_local(lambda wb, bb, xb: wb @ xb + bb,
+    f = grid.bank_local(_bias_mv,
                         in_specs=(P(AXIS), P(AXIS), P()))
     with t.phase("dpu"):
         out = sync(f(dw, db, dx))
@@ -66,7 +75,7 @@ def pim_b(grid: BankGrid, w: dict, x: np.ndarray):
 
 @functools.cache
 def _local_b(grid: BankGrid):
-    return jax.jit(grid.bank_local(lambda wb, bb, xb: wb @ xb + bb,
+    return jax.jit(grid.bank_local(_bias_mv,
                                    in_specs=(P(AXIS), P(AXIS), P())))
 
 
@@ -128,7 +137,7 @@ def pim_g(grid: BankGrid, w: dict, x: np.ndarray):
         dg = sync(grid.to_banks(gc))
         du = sync(grid.to_banks(uc))
         dx = sync(grid.broadcast(np.asarray(x)))
-    f = grid.bank_local(lambda gb, ub, xb: _silu_f32(gb @ xb) * (ub @ xb),
+    f = grid.bank_local(_gated_mv,
                         in_specs=(P(AXIS), P(AXIS), P()))
     with t.phase("dpu"):
         out = sync(f(dg, du, dx))
@@ -139,9 +148,8 @@ def pim_g(grid: BankGrid, w: dict, x: np.ndarray):
 
 @functools.cache
 def _local_g(grid: BankGrid):
-    return jax.jit(grid.bank_local(
-        lambda gb, ub, xb: _silu_f32(gb @ xb) * (ub @ xb),
-        in_specs=(P(AXIS), P(AXIS), P())))
+    return jax.jit(grid.bank_local(_gated_mv,
+                                   in_specs=(P(AXIS), P(AXIS), P())))
 
 
 def _split_resident_g(grid, n_chunks, w):

@@ -18,7 +18,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import transfer as tx
 from repro.core.banked import AXIS, BankGrid
-from .common import ChunkedWorkload, PhaseTimer, pad_chunks, register_chunked, sync
+from .common import (ChunkedWorkload, PhaseTimer, matvec, pad_chunks,
+                     register_chunked, sync)
 
 
 def ref(weights: list[np.ndarray], x: np.ndarray) -> np.ndarray:
@@ -31,7 +32,7 @@ def ref(weights: list[np.ndarray], x: np.ndarray) -> np.ndarray:
 def pim(grid: BankGrid, weights: list[np.ndarray], x: np.ndarray):
     t = PhaseTimer()
     f = grid.bank_local(
-        lambda wb, hb: jnp.maximum(wb @ hb, 0),
+        lambda wb, hb: jnp.maximum(matvec(wb, hb), 0),
         in_specs=(P(AXIS), P()))
     h = np.asarray(x)
     for li, w in enumerate(weights):
@@ -59,7 +60,7 @@ def pim(grid: BankGrid, weights: list[np.ndarray], x: np.ndarray):
 @functools.cache
 def _local(grid: BankGrid):
     return jax.jit(grid.bank_local(
-        lambda wb, hb: jnp.maximum(wb @ hb, 0),
+        lambda wb, hb: jnp.maximum(matvec(wb, hb), 0),
         in_specs=(P(AXIS), P())))
 
 
@@ -77,7 +78,7 @@ def _split_resident(grid, n_chunks, weights):
 def _split_varying(grid, n_chunks, res_meta, weights, x):
     h = grid.broadcast(np.asarray(x))
     for dw in res_meta["dws"]:
-        h = jnp.maximum(dw @ h, 0)
+        h = jnp.maximum(matvec(dw, h), 0)
     return {"m": res_meta["m"], "per": res_meta["per"], "dh": h}, None
 
 

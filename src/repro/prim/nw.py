@@ -73,13 +73,10 @@ def pim(grid: BankGrid, s1: np.ndarray, s2: np.ndarray, block: int = 32):
     S[:, 0] = -GAP * np.arange(mp + 1)
 
     n_banks = grid.n_banks
-    kernel = jax.jit(jax.vmap(_nw_block))
-
-    def compute_blocks(tops, lefts, corners, s1bs, s2bs):
-        f = grid.bank_local(
-            lambda tt, ll, cc, aa, bb: kernel(tt[0], ll[0], cc[0],
-                                              aa[0], bb[0])[None])
-        return f(tops, lefts, corners, s1bs, s2bs)
+    kernel = jax.vmap(_nw_block)
+    compute_blocks = grid.bank_local(
+        lambda tt, ll, cc, aa, bb: kernel(tt[0], ll[0], cc[0],
+                                          aa[0], bb[0])[None])
 
     for d in range(nbx + nby - 1):
         cells = [(bi, d - bi) for bi in range(max(0, d - nby + 1),

@@ -68,8 +68,7 @@ cfg = ModelConfig(d_model=32, d_ff=16, moe_experts=8, moe_top_k=2,
 params, _ = moe.init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32), jnp.float32)
 y0, _ = moe.apply(params, cfg, x)
-from repro.core.compat import set_mesh
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     y1, _ = jax.jit(lambda p, xx: moe.apply_ep(p, cfg, xx))(params, x)
     g2 = jax.jit(jax.grad(lambda p: moe.apply_ep(p, cfg, x)[0].sum()
                           .astype(jnp.float32)))(params)

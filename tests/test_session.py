@@ -265,6 +265,41 @@ def test_resident_false_disables_cache(bank_grid, rng):
         s.close()
 
 
+def test_resident_budget_from_device_memory(bank_grid):
+    """The default budget is each bank's free device memory less the
+    in-flight headroom; a backend without memory stats (the CPU) keeps the
+    modelled MRAM capacity."""
+    import types
+
+    from repro.core.perfmodel import mram_capacity_bytes
+    from repro.pim.session import resident_budget
+
+    assert resident_budget(bank_grid, 1 << 20) == \
+        mram_capacity_bytes(bank_grid.n_banks)
+    with pim.session(grid=bank_grid) as s:
+        assert s.cache.budget_bytes == mram_capacity_bytes(s.n_banks)
+
+    class Dev:
+        def __init__(self, limit, used):
+            self.stats = {"bytes_limit": limit, "bytes_in_use": used}
+
+        def memory_stats(self):
+            return self.stats
+
+    def grid(*devs):
+        return types.SimpleNamespace(
+            n_banks=len(devs),
+            mesh=types.SimpleNamespace(devices=np.array(devs, object)))
+
+    gb = 1 << 30
+    assert resident_budget(grid(Dev(16 * gb, 4 * gb)), gb) == 11 * gb
+    assert resident_budget(grid(Dev(16 * gb, 4 * gb), Dev(16 * gb, 15 * gb)),
+                           2 * gb) == 10 * gb        # a full bank adds 0
+    none = grid(Dev(16 * gb, 0))
+    none.mesh.devices.flat[0].stats = None
+    assert resident_budget(none, gb) == mram_capacity_bytes(1)
+
+
 def test_close_releases_resident_operands(bank_grid, rng):
     entry = pim.registry()["GEMV"]
     args = entry.make_args(rng, 1)

@@ -17,6 +17,8 @@ def test_paper_claim_parallel_beats_serial_transfer(bank_grid):
     """Key Obs. 8/9 analogue: parallel transfers sustain ≥ serial ones."""
     import repro.core.transfer as tx
     buf = np.zeros((bank_grid.n_banks, 1 << 16), np.int64)
+    tx.push_parallel(bank_grid, buf)   # warm-up: the first transfer of a
+    tx.push_serial(bank_grid, list(buf))   # process pays one-off set-up
     _, par = tx.push_parallel(bank_grid, buf)
     _, ser = tx.push_serial(bank_grid, list(buf))
     assert par.nbytes == ser.nbytes

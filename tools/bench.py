@@ -78,6 +78,7 @@ sys.path.insert(0, str(_HERE))
 from check_bench import SCHEMA, validate  # noqa: E402
 
 from repro.runtime.autotune import DEFAULT_N_CHUNKS  # noqa: E402
+from repro.launch.cli import cpu_rehearsal_env, enable_compile_cache  # noqa: E402
 
 
 def env_info() -> dict:
@@ -583,8 +584,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     if args.banks:
-        env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_"
-                                         f"count={args.banks}")
+        env = cpu_rehearsal_env(args.banks)
         cmd = [sys.executable, str(_HERE / "bench.py"), "--out", args.out]
         if args.smoke:
             cmd.append("--smoke")
@@ -620,4 +620,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())
