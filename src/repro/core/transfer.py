@@ -27,31 +27,6 @@ from jax.sharding import PartitionSpec as P
 
 from .banked import BankGrid, RankGrid
 
-_get_tracer = None
-
-
-def _tracer():
-    """The active span tracer (DESIGN.md §11) — bound lazily because
-    ``repro.runtime`` imports this module at package-init time (importing
-    ``repro.runtime.trace`` at the top here would be circular).  After the
-    first call this is one global read + one function call."""
-    global _get_tracer
-    if _get_tracer is None:
-        from repro.runtime.trace import get_tracer
-        _get_tracer = get_tracer
-    return _get_tracer()
-
-
-def _trace_xfer(rec: "TransferRecord", t0: float) -> "TransferRecord":
-    """Emit a span mirroring a TransferRecord (no-op when tracing is off);
-    returns the record so call sites stay one-liners."""
-    tr = _tracer()
-    if tr.enabled:
-        tr.emit(rec.kind, "transfer", t0, t0 + rec.seconds,
-                bytes=rec.nbytes)
-    return rec
-
-
 @dataclasses.dataclass
 class TransferRecord:
     kind: str
@@ -61,6 +36,15 @@ class TransferRecord:
     @property
     def bandwidth(self) -> float:
         return self.nbytes / self.seconds if self.seconds else float("inf")
+
+
+def _record(sp, kind: str, nbytes: int, t0: float) -> TransferRecord:
+    """The transfer begun at ``t0`` and ending now, as a record and on its
+    span (DESIGN.md §11): the span carries the record's own interval."""
+    t1 = time.perf_counter()
+    sp.stamp(t0, t1)
+    sp.tag(bytes=nbytes)
+    return TransferRecord(kind, nbytes, t1 - t0)
 
 
 def _nbytes(x) -> int:
@@ -142,37 +126,37 @@ def split_chunks_ranked(x: np.ndarray, n_ranks: int, n_chunks: int,
 # -- transfer modes ----------------------------------------------------------
 
 def push_parallel(grid: BankGrid, x, spec: P | None = None):
-    t0 = time.perf_counter()
-    out = grid.to_banks(x, spec)
-    jax.block_until_ready(out)
-    return out, _trace_xfer(TransferRecord(
-        "cpu_dpu_parallel", _nbytes(np.asarray(x)),
-        time.perf_counter() - t0), t0)
+    nbytes = _nbytes(np.asarray(x))
+    with span("cpu_dpu_parallel", "transfer") as sp:
+        t0 = time.perf_counter()
+        out = grid.to_banks(x, spec)
+        jax.block_until_ready(out)
+        return out, _record(sp, "cpu_dpu_parallel", nbytes, t0)
 
 
 def push_serial(grid: BankGrid, chunks: Sequence[np.ndarray]):
-    t0 = time.perf_counter()
-    out = grid.serial_to_banks(chunks)
-    jax.block_until_ready(out)
     nbytes = sum(_nbytes(c) for c in chunks)
-    return out, _trace_xfer(TransferRecord(
-        "cpu_dpu_serial", nbytes, time.perf_counter() - t0), t0)
+    with span("cpu_dpu_serial", "transfer") as sp:
+        t0 = time.perf_counter()
+        out = grid.serial_to_banks(chunks)
+        jax.block_until_ready(out)
+        return out, _record(sp, "cpu_dpu_serial", nbytes, t0)
 
 
 def push_broadcast(grid: BankGrid, x):
-    t0 = time.perf_counter()
-    out = grid.broadcast(x)
-    jax.block_until_ready(out)
-    return out, _trace_xfer(TransferRecord(
-        "cpu_dpu_broadcast", _nbytes(np.asarray(x)),
-        time.perf_counter() - t0), t0)
+    nbytes = _nbytes(np.asarray(x))
+    with span("cpu_dpu_broadcast", "transfer") as sp:
+        t0 = time.perf_counter()
+        out = grid.broadcast(x)
+        jax.block_until_ready(out)
+        return out, _record(sp, "cpu_dpu_broadcast", nbytes, t0)
 
 
 def pull_parallel(grid: BankGrid, x):
-    t0 = time.perf_counter()
-    host = grid.from_banks(x)
-    return host, _trace_xfer(TransferRecord(
-        "dpu_cpu_parallel", _nbytes(host), time.perf_counter() - t0), t0)
+    with span("dpu_cpu_parallel", "transfer") as sp:
+        t0 = time.perf_counter()
+        host = grid.from_banks(x)
+        return host, _record(sp, "dpu_cpu_parallel", _nbytes(host), t0)
 
 
 # -- async variants (double-buffering building blocks) -----------------------
@@ -186,11 +170,11 @@ def pull_parallel(grid: BankGrid, x):
 
 def push_parallel_async(grid: BankGrid, x, spec: P | None = None):
     """Parallel CPU→bank scatter without the completion barrier."""
-    t0 = time.perf_counter()
-    out = grid.to_banks(x, spec)
-    return out, _trace_xfer(TransferRecord(
-        "cpu_dpu_async", _nbytes(np.asarray(x)),
-        time.perf_counter() - t0), t0)
+    nbytes = _nbytes(np.asarray(x))
+    with span("cpu_dpu_async", "transfer") as sp:
+        t0 = time.perf_counter()
+        out = grid.to_banks(x, spec)
+        return out, _record(sp, "cpu_dpu_async", nbytes, t0)
 
 
 def pull_async(x):
@@ -203,19 +187,19 @@ def pull_async(x):
         pass  # non-jax arrays (already host) resolve immediately
 
     def resolve():
-        t0 = time.perf_counter()
-        host = np.asarray(jax.device_get(x))
-        return host, _trace_xfer(TransferRecord(
-            "dpu_cpu_async", _nbytes(host), time.perf_counter() - t0), t0)
+        with span("dpu_cpu_async", "transfer") as sp:
+            t0 = time.perf_counter()
+            host = np.asarray(jax.device_get(x))
+            return host, _record(sp, "dpu_cpu_async", _nbytes(host), t0)
     return resolve
 
 
 def pull_serial(grid: BankGrid, xs: Sequence):
-    t0 = time.perf_counter()
-    host = [np.asarray(jax.device_get(x)) for x in xs]
-    nbytes = sum(_nbytes(h) for h in host)
-    return host, _trace_xfer(TransferRecord(
-        "dpu_cpu_serial", nbytes, time.perf_counter() - t0), t0)
+    with span("dpu_cpu_serial", "transfer") as sp:
+        t0 = time.perf_counter()
+        host = [np.asarray(jax.device_get(x)) for x in xs]
+        return host, _record(sp, "dpu_cpu_serial",
+                             sum(_nbytes(h) for h in host), t0)
 
 
 # -- rank-parallel transfers (DESIGN.md §10) ---------------------------------
@@ -233,12 +217,12 @@ def push_ranks_async(grid: RankGrid, per_rank: Sequence, spec: P | None = None):
     (per-rank device arrays, TransferRecord accounting enqueue cost)."""
     if len(per_rank) > grid.n_ranks:
         raise ValueError(f"{len(per_rank)} payloads for {grid.n_ranks} ranks")
-    t0 = time.perf_counter()
-    outs = [grid.rank_view(r).to_banks(x, spec)
-            for r, x in enumerate(per_rank)]
     nbytes = sum(_nbytes(np.asarray(x)) for x in per_rank)
-    return outs, _trace_xfer(TransferRecord(
-        "cpu_dpu_rank_async", nbytes, time.perf_counter() - t0), t0)
+    with span("cpu_dpu_rank_async", "transfer") as sp:
+        t0 = time.perf_counter()
+        outs = [grid.rank_view(r).to_banks(x, spec)
+                for r, x in enumerate(per_rank)]
+        return outs, _record(sp, "cpu_dpu_rank_async", nbytes, t0)
 
 
 def pull_ranks_async(xs: Sequence):
@@ -252,9 +236,14 @@ def pull_ranks_async(xs: Sequence):
             pass
 
     def resolve():
-        t0 = time.perf_counter()
-        host = [np.asarray(jax.device_get(x)) for x in xs]
-        nbytes = sum(_nbytes(h) for h in host)
-        return host, _trace_xfer(TransferRecord(
-            "dpu_cpu_rank_async", nbytes, time.perf_counter() - t0), t0)
+        with span("dpu_cpu_rank_async", "transfer") as sp:
+            t0 = time.perf_counter()
+            host = [np.asarray(jax.device_get(x)) for x in xs]
+            return host, _record(sp, "dpu_cpu_rank_async",
+                                 sum(_nbytes(h) for h in host), t0)
     return resolve
+
+
+# bound last: ``repro.runtime`` imports this module while it initialises,
+# so ``repro.runtime.trace`` can only be imported once the names above exist
+from repro.runtime.trace import span  # noqa: E402

@@ -59,7 +59,7 @@ from repro.runtime.qos import RequestOptions
 from repro.runtime.resident import ResidentCache, unwrap_handles
 from repro.runtime.scheduler import PimRequest, PimScheduler
 from repro.runtime.telemetry import Telemetry
-from repro.runtime.trace import NULL_SPAN, Tracer, set_tracer
+from repro.runtime.trace import Tracer, set_tracer, span
 
 if TYPE_CHECKING:  # annotation-only: importing repro.prim pulls the suite
     from repro.prim.registry import WorkloadEntry
@@ -368,10 +368,8 @@ class PimSession:
         serialized-only execution is picked per registry entry; a tuned plan
         overrides the chunk count when installed."""
         self._check_open("run")
-        tr = self._tracer
-        with (tr.span(f"run:{workload}", "session", track="session",
-                      workload=workload) if tr is not None
-              else NULL_SPAN):
+        with span(f"run:{workload}", "session", track="session",
+                  workload=workload):
             req = self._sched.submit(workload, *args, options=options,
                                      priority=priority)
             if self._serving:
@@ -394,10 +392,8 @@ class PimSession:
         args_list = [tuple(a) for a in arg_stream]
         if not args_list:
             return []
-        tr = self._tracer
-        with (tr.span(f"map:{workload}", "session", track="session",
-                      workload=workload, requests=len(args_list))
-              if tr is not None else NULL_SPAN):
+        with span(f"map:{workload}", "session", track="session",
+                  workload=workload, requests=len(args_list)):
             return self._map(workload, args_list, options)
 
     def _map(self, workload: str, args_list: list,

@@ -36,7 +36,15 @@ def matvec(a, x):
 
 @dataclasses.dataclass
 class PhaseTimes:
+    """Seconds per paper phase.  A serialized ``pim()`` syncs the device at
+    every boundary, so each bucket holds its phase's device time.  The
+    chunk pipeline (``runtime/pipeline.py``) never syncs between phases, so
+    there they are host time: ``cpu_dpu`` issuing scatters, ``dpu_cpu``
+    waiting for chunks and copying them out, ``inter_dpu`` merging."""
+
     cpu_dpu: float = 0.0
+    #: in the pipeline the asynchronous enqueue of the compute phase
+    #: (``launch``), not the device's time: that is in the device trace
     dpu: float = 0.0
     inter_dpu: float = 0.0
     dpu_cpu: float = 0.0

@@ -27,6 +27,15 @@ its own double-buffered pipeline over its own devices (one thread per rank
 of the paper's rank-parallel CPU↔DPU transfers).  The host merges each
 request's parts in global chunk order, so order-sensitive merges (SCAN's
 running offset) stay correct.
+
+Spans (``runtime/trace.py``, DESIGN.md §11), on the thread that runs the
+pipeline, each tagged with the request's id: ``split`` (cache acquire and
+split, including per-request broadcasts such as GEMV's ``x``),
+``scatter`` / ``scatter_cached``, ``launch`` (the asynchronous enqueue of
+the compute phase and of its output's copy to the host), ``device_wait``
+(blocked on a chunk's output), ``copy_out`` (the host copy after it) and
+``merge``.  The same intervals stamp each request's ``device_wait_s`` and
+``host_self_s`` (the rest of its time inside those spans).
 """
 from __future__ import annotations
 
@@ -42,7 +51,7 @@ from repro.core.transfer import tree_nbytes
 
 from .resident import unwrap_handles
 from .telemetry import RequestRecord, _phases
-from .trace import get_tracer
+from .trace import NULL_SPAN, get_tracer, span, tracing
 
 if TYPE_CHECKING:  # annotation-only: importing repro.prim pulls the suite
     from repro.prim.common import ChunkedWorkload, PhaseTimes
@@ -68,15 +77,112 @@ def _host_prefetch(outs) -> None:
 
 
 class _Buckets:
-    """Accumulate host wall time into PhaseTimes buckets."""
+    """Host wall time of one request on one pipeline thread: the
+    PhaseTimes buckets, the seconds inside the request's pipeline spans
+    (``busy``) and, of those, blocked on its chunks (``wait``)."""
 
     def __init__(self):
         self.times = _phases()
+        self.busy = 0.0
+        self.wait = 0.0
 
-    def add(self, phase: str, t0: float) -> float:
+    def add(self, phase: str | None, t0: float, wait: bool = False) -> float:
         t1 = time.perf_counter()
-        setattr(self.times, phase, getattr(self.times, phase) + (t1 - t0))
+        if phase is not None:
+            setattr(self.times, phase, getattr(self.times, phase) + (t1 - t0))
+        self.busy += t1 - t0
+        if wait:
+            self.wait += t1 - t0
         return t1
+
+
+def _req_ids(records, n_req: int) -> list:
+    """Span tags: each request's telemetry id when records ride along,
+    else its batch-local index."""
+    if records is None:
+        return list(range(n_req))
+    return [rec.request_id for rec in records]
+
+
+def _stamp_plan(records, plan) -> None:
+    """A tuned plan's promises, on every record of the batch."""
+    stage_pred = dict(getattr(plan, "predicted_stage_s", {}) or {})
+    for rec in records:
+        rec.tuned = True
+        rec.predicted_overlap = plan.predicted_overlap
+        if stage_pred:
+            rec.predicted_stage_s = dict(stage_pred)
+
+
+def _stamp_split(rec, n_chunks: int, hit: bool, plan) -> None:
+    rec.n_chunks = n_chunks
+    rec.cache_hit = hit
+    if hit and plan is not None and getattr(plan, "warm_predicted_overlap",
+                                            0.0):
+        rec.predicted_overlap = plan.warm_predicted_overlap
+
+
+def _meta_cached_span(workload, ent, hit: bool, req):
+    """A meta-resident hit (BS): the skipped broadcast happens at split
+    time, so its ``scatter_cached`` span wraps the split, not a chunk."""
+    if ent is None or not hit or ent.chunk_resident:
+        return NULL_SPAN
+    return span("scatter_cached", "cpu_dpu", req=req,
+                workload=workload.name, bytes=ent.nbytes,
+                fingerprint=ent.fingerprint)
+
+
+def _scatter_chunk(view, workload, meta, ent, gidx, chunk, args, total,
+                   req):
+    """Push one chunk to the banks, or serve it from the request's
+    resident entry (DESIGN.md §12).  The push is exactly-once: the entry
+    lock is held across it, so a second filler of the same fingerprint can
+    only observe the stored buffers, never race the push."""
+    name = workload.name
+    if ent is None or not ent.chunk_resident:
+        with span("scatter", "cpu_dpu", req=req, chunk=gidx, workload=name,
+                  bytes=tree_nbytes(chunk) if tracing() else None):
+            return workload.scatter(view, meta, chunk)
+    with ent.lock:
+        bufs = ent.get(gidx)
+        if bufs is not None:
+            with span("scatter_cached", "cpu_dpu", req=req, chunk=gidx,
+                      workload=name,
+                      bytes=ent.nbytes // max(1, ent.expected_chunks),
+                      fingerprint=ent.fingerprint):
+                return bufs
+        if chunk is None:                # placeholder outlived the entry
+            chunk = _refill_chunk(view, workload, args, total, gidx)
+        with span("scatter", "cpu_dpu", req=req, chunk=gidx, workload=name,
+                  bytes=tree_nbytes(chunk) if tracing() else None):
+            bufs = workload.scatter(view, meta, chunk)
+        ent.store(gidx, bufs)
+        return bufs
+
+
+def _launch_chunk(view, workload, meta, bufs, req, gidx, bucket):
+    """Enqueue one chunk's compute phase and the copy of its output to the
+    host; neither waits for the device."""
+    ts = time.perf_counter()
+    with span("launch", "dpu", req=req, chunk=gidx, workload=workload.name):
+        outs = workload.compute(view, meta, bufs)
+        _host_prefetch(outs)
+    bucket.add("dpu", ts)
+    return outs
+
+
+def _retire_chunk(view, workload, meta, outs, req, gidx, bucket):
+    """Block for one chunk's output, then copy it to the host; returns the
+    host part and the time it landed."""
+    ts = time.perf_counter()
+    with span("device_wait", "dpu_cpu", req=req, chunk=gidx,
+              workload=workload.name):
+        jax.block_until_ready(outs)
+    t1 = bucket.add("dpu_cpu", ts, wait=True)
+    with span("copy_out", "dpu_cpu", req=req, chunk=gidx,
+              workload=workload.name):
+        part = workload.retrieve(view, meta, outs)
+    return part, bucket.add("dpu_cpu", t1)
 
 
 def _effective_chunks(workload, n_chunks, plan, cache) -> tuple[int, bool]:
@@ -162,23 +268,22 @@ def run_pipelined_many(grid: BankGrid, workload: ChunkedWorkload,
 
     ``requests`` is a sequence of argument tuples for ``workload``.  Returns
     the list of results (plus per-request makespans and phase buckets when
-    ``_full``).  Requests complete in submission order; a request's result is
-    merged as soon as its last chunk retires, while later requests' chunks
-    are already in flight.  A :class:`~repro.runtime.autotune.TunedPlan`
-    overrides ``n_chunks`` and stamps its predicted overlap on the records;
-    a :class:`~repro.runtime.resident.ResidentCache` lets requests whose
+    ``_full``).  Requests complete in submission order; each is split just
+    before its first chunk is scattered (its host work overlaps the chunks
+    already in flight), and its result is merged as soon as its last chunk
+    retires, while later requests' chunks are already in flight.  A
+    request's service starts at its split.  A
+    :class:`~repro.runtime.autotune.TunedPlan` overrides ``n_chunks`` and
+    stamps its predicted overlap on the records; a
+    :class:`~repro.runtime.resident.ResidentCache` lets requests whose
     resident operand is already placed skip the scatter stage (DESIGN.md
-    §12) — served chunks emit ``scatter:cached`` spans instead of pushes.
+    §12) — served chunks emit ``scatter_cached`` spans instead of pushes.
     """
     n_chunks, use_cache = _effective_chunks(workload, n_chunks, plan, cache)
     if plan is not None and records is not None:
-        stage_pred = dict(getattr(plan, "predicted_stage_s", {}) or {})
-        for rec in records:
-            rec.tuned = True
-            rec.predicted_overlap = plan.predicted_overlap
-            if stage_pred:
-                rec.predicted_stage_s = dict(stage_pred)
+        _stamp_plan(records, plan)
     n_req = len(requests)
+    rids = _req_ids(records, n_req)
     metas: list = [None] * n_req
     entries: list = [None] * n_req        # ResidentEntry per request
     flat: list = []                       # (req_idx, chunk_idx, chunk)
@@ -188,110 +293,72 @@ def run_pipelined_many(grid: BankGrid, workload: ChunkedWorkload,
     parts: list = [[] for _ in range(n_req)]
     chunk_count = [0] * n_req
     results: list = [None] * n_req
-    tr = get_tracer()                     # off-by-default span tracer
-    chunk_bytes: dict = {}                # per-request span tag cache: chunks
-                                          # are equal-shaped, size them once
+    unsplit = iter(range(n_req))
 
-    def _rid(i):
-        return records[i].request_id if records is not None else i
+    def split_next() -> bool:
+        """Split the next request onto the chunk stream; False when every
+        request is split."""
+        i = next(unsplit, None)
+        if i is None:
+            return False
+        ts = t_start[i] = time.perf_counter()
+        with span("split", "split", req=rids[i], workload=workload.name):
+            ent, hit = (cache.acquire(workload, requests[i],
+                                      (grid.n_banks, 1, n_chunks))
+                        if use_cache else (None, False))
+            entries[i] = ent
+            with _meta_cached_span(workload, ent, hit, rids[i]):
+                metas[i], chunks = _split_with_cache(
+                    grid, workload, requests[i], n_chunks, ent, hit=hit)
+        bucket[i].add(None, ts)
+        chunk_count[i] = len(chunks)
+        flat.extend((i, ci, c) for ci, c in enumerate(chunks))
+        if records is not None:
+            _stamp_split(records[i], len(chunks), hit, plan)
+        return True
 
-    t0 = time.perf_counter()
+    def ready(k) -> bool:
+        """Whether chunk ``k`` of the stream exists, splitting requests
+        until it does."""
+        while len(flat) <= k and split_next():
+            pass
+        return k < len(flat)
 
     def scatter(k):
         i, ci, chunk = flat[k]
-        if not t_start[i]:
-            t_start[i] = time.perf_counter()
         ts = time.perf_counter()
-        ent = entries[i]
-        served = False
-        if ent is not None and ent.chunk_resident:
-            # exactly-once device push: the entry lock is held across the
-            # scatter so a second filler of the same fingerprint can only
-            # observe the stored buffers, never race the push
-            with ent.lock:
-                bufs = ent.get(ci)
-                if bufs is None:
-                    if chunk is None:    # placeholder outlived the entry
-                        chunk = _refill_chunk(grid, workload, requests[i],
-                                              n_chunks, ci)
-                    bufs = workload.scatter(grid, metas[i], chunk)
-                    ent.store(ci, bufs)
-                else:
-                    served = True
-        else:
-            bufs = workload.scatter(grid, metas[i], chunk)
-        t1 = bucket[i].add("cpu_dpu", ts)
-        if tr.enabled:
-            if served:
-                nb = ent.nbytes // max(1, ent.expected_chunks)
-                tr.emit("scatter:cached", "cpu_dpu", ts, t1,
-                        workload=workload.name, req=_rid(i), chunk=ci,
-                        bytes=nb, fingerprint=ent.fingerprint)
-            else:
-                if (nb := chunk_bytes.get(i)) is None:
-                    nb = chunk_bytes[i] = tree_nbytes(chunk)
-                tr.emit("scatter", "cpu_dpu", ts, t1, workload=workload.name,
-                        req=_rid(i), chunk=ci, bytes=nb)
+        bufs = _scatter_chunk(grid, workload, metas[i], entries[i], ci,
+                              chunk, requests[i], n_chunks, rids[i])
+        bucket[i].add("cpu_dpu", ts)
         return bufs
 
     def retire(entry):
         """Block for one in-flight chunk and fold it into its request."""
         i, ci, outs = entry
-        ts = time.perf_counter()
-        parts[i].append(workload.retrieve(grid, metas[i], outs))
-        t1 = bucket[i].add("dpu_cpu", ts)
-        if tr.enabled:
-            tr.emit("retrieve", "dpu_cpu", ts, t1, workload=workload.name,
-                    req=_rid(i), chunk=ci)
+        part, t1 = _retire_chunk(grid, workload, metas[i], outs, rids[i],
+                                 ci, bucket[i])
+        parts[i].append(part)
         if len(parts[i]) == chunk_count[i]:
-            results[i] = workload.merge(grid, metas[i], parts[i])
+            with span("merge", "inter_dpu", req=rids[i],
+                      workload=workload.name):
+                results[i] = workload.merge(grid, metas[i], parts[i])
             t_done[i] = bucket[i].add("inter_dpu", t1)
-            if tr.enabled:
-                tr.emit("merge", "inter_dpu", t1, t_done[i],
-                        workload=workload.name, req=_rid(i),
-                        chunks=chunk_count[i])
 
+    t0 = time.perf_counter()
     try:
-        for i, args in enumerate(requests):
-            ts = time.perf_counter()
-            ent, hit = (cache.acquire(workload, args,
-                                      (grid.n_banks, 1, n_chunks))
-                        if use_cache else (None, False))
-            entries[i] = ent
-            metas[i], chunks = _split_with_cache(grid, workload, args,
-                                                 n_chunks, ent, hit=hit)
-            if (ent is not None and hit and not ent.chunk_resident
-                    and tr.enabled):
-                # meta-resident hit (BS): the skipped broadcast happened at
-                # split time, so the cached span lands here, not per chunk
-                tr.emit("scatter:cached", "cpu_dpu", ts, time.perf_counter(),
-                        workload=workload.name, req=_rid(i),
-                        bytes=ent.nbytes, fingerprint=ent.fingerprint)
-            chunk_count[i] = len(chunks)
-            flat.extend((i, ci, c) for ci, c in enumerate(chunks))
-            if records is not None:
-                records[i].n_chunks = len(chunks)
-                records[i].cache_hit = hit
-                if (hit and plan is not None
-                        and getattr(plan, "warm_predicted_overlap", 0.0)):
-                    records[i].predicted_overlap = plan.warm_predicted_overlap
-
         in_flight: list = []
-        bufs = scatter(0) if flat else None
-        for k in range(len(flat)):
+        bufs = scatter(0) if ready(0) else None
+        k = 0
+        while k < len(flat):
             i, ci, _ = flat[k]
-            ts = time.perf_counter()
-            outs = workload.compute(grid, metas[i], bufs)
-            t1 = bucket[i].add("dpu", ts)
-            if tr.enabled:
-                tr.emit("compute", "dpu", ts, t1, workload=workload.name,
-                        req=_rid(i), chunk=ci)
-            if k + 1 < len(flat):
+            outs = _launch_chunk(grid, workload, metas[i], bufs, rids[i], ci,
+                                 bucket[i])
+            if ready(k + 1):
                 bufs = scatter(k + 1)    # overlaps compute of chunk k
-            _host_prefetch(outs)         # start draining chunk k early
             in_flight.append((i, ci, outs))
             if len(in_flight) > 1:       # retire k-1 while k computes
                 retire(in_flight.pop(0))
+            k += 1
         while in_flight:
             retire(in_flight.pop(0))
     finally:
@@ -307,6 +374,8 @@ def run_pipelined_many(grid: BankGrid, workload: ChunkedWorkload,
             rec.t_start = t_start[i] or t0
             rec.t_finish = t_done[i]
             rec.phases = bucket[i].times
+            rec.device_wait_s = bucket[i].wait
+            rec.host_self_s = bucket[i].busy - bucket[i].wait
     if _full:
         return results, makespans, [b.times for b in bucket]
     return results
@@ -315,11 +384,6 @@ def run_pipelined_many(grid: BankGrid, workload: ChunkedWorkload,
 # ---------------------------------------------------------------------------
 # rank-parallel pipelines (DESIGN.md §10)
 # ---------------------------------------------------------------------------
-
-def _req_id(records, i: int) -> int:
-    """Span tag: the request's telemetry id when records ride along, else
-    its batch-local index."""
-    return records[i].request_id if records is not None else i
 
 def _resolve_ranks(grid, n_ranks, plan) -> int:
     """Effective rank count.  An explicit caller ``n_ranks`` wins — that is
@@ -340,7 +404,7 @@ def _resolve_ranks(grid, n_ranks, plan) -> int:
 
 
 def _rank_worker(view, workload, metas, stream, bucket, t_start, t_retired,
-                 entries=None, requests=None, split_total=0):
+                 entries, requests, split_total, rids):
     """One rank's double-buffered pipeline over its assigned chunk stream.
 
     ``stream`` is an ordered list of (req_idx, global_chunk_idx, chunk);
@@ -358,67 +422,31 @@ def _rank_worker(view, workload, metas, stream, bucket, t_start, t_retired,
     parts: dict[int, list] = {}
     if not stream:
         return parts
-    tr = get_tracer()
-    chunk_bytes: dict = {}                # per-request cache (equal-shaped)
 
     def scatter(k):
         i, gidx, chunk = stream[k]
         if not t_start[i]:
             t_start[i] = time.perf_counter()
         ts = time.perf_counter()
-        ent = entries[i] if entries is not None else None
-        served = False
-        if ent is not None and ent.chunk_resident:
-            with ent.lock:
-                bufs = ent.get(gidx)
-                if bufs is None:
-                    if chunk is None and requests is not None:
-                        # placeholder outlived the entry (see _refill_chunk)
-                        chunk = _refill_chunk(view, workload, requests[i],
-                                              split_total, gidx)
-                    bufs = workload.scatter(view, metas[i], chunk)
-                    ent.store(gidx, bufs)
-                else:
-                    served = True
-        else:
-            bufs = workload.scatter(view, metas[i], chunk)
-        t1 = bucket[i].add("cpu_dpu", ts)
-        if tr.enabled:
-            if served:
-                nb = ent.nbytes // max(1, ent.expected_chunks)
-                tr.emit("scatter:cached", "cpu_dpu", ts, t1,
-                        workload=workload.name, req=i, chunk=gidx,
-                        bytes=nb, fingerprint=ent.fingerprint)
-            else:
-                if (nb := chunk_bytes.get(i)) is None:
-                    nb = chunk_bytes[i] = tree_nbytes(chunk)
-                tr.emit("scatter", "cpu_dpu", ts, t1, workload=workload.name,
-                        req=i, chunk=gidx, bytes=nb)
+        bufs = _scatter_chunk(view, workload, metas[i], entries[i], gidx,
+                              chunk, requests[i], split_total, rids[i])
+        bucket[i].add("cpu_dpu", ts)
         return bufs
 
     def retire(entry):
         i, gidx, outs = entry
-        ts = time.perf_counter()
-        parts.setdefault(i, []).append(
-            (gidx, workload.retrieve(view, metas[i], outs)))
-        t_retired[i] = bucket[i].add("dpu_cpu", ts)
-        if tr.enabled:
-            tr.emit("retrieve", "dpu_cpu", ts, t_retired[i],
-                    workload=workload.name, req=i, chunk=gidx)
+        part, t_retired[i] = _retire_chunk(view, workload, metas[i], outs,
+                                           rids[i], gidx, bucket[i])
+        parts.setdefault(i, []).append((gidx, part))
 
     in_flight: list = []
     bufs = scatter(0)
     for k in range(len(stream)):
         i, gidx = stream[k][0], stream[k][1]
-        ts = time.perf_counter()
-        outs = workload.compute(view, metas[i], bufs)
-        t1 = bucket[i].add("dpu", ts)
-        if tr.enabled:
-            tr.emit("compute", "dpu", ts, t1, workload=workload.name,
-                    req=i, chunk=gidx)
+        outs = _launch_chunk(view, workload, metas[i], bufs, rids[i], gidx,
+                             bucket[i])
         if k + 1 < len(stream):
             bufs = scatter(k + 1)        # overlaps compute of chunk k
-        _host_prefetch(outs)
         in_flight.append((i, gidx, outs))
         if len(in_flight) > 1:
             retire(in_flight.pop(0))
@@ -455,15 +483,11 @@ def run_pipelined_ranked(grid, workload: ChunkedWorkload,
                                   n_chunks=n_chunks, plan=plan,
                                   records=records, cache=cache, _full=_full)
     if records is not None and plan is not None:
-        stage_pred = dict(getattr(plan, "predicted_stage_s", {}) or {})
-        for rec in records:
-            rec.tuned = True
-            rec.predicted_overlap = plan.predicted_overlap
-            if stage_pred:
-                rec.predicted_stage_s = dict(stage_pred)
+        _stamp_plan(records, plan)
 
     rep = grid.rank_view(0)          # all views share the per-rank geometry
     n_req = len(requests)
+    rids = _req_ids(records, n_req)
     # every rank splits with its *own* view: split is deterministic host
     # work (identical chunks), but several workloads broadcast per-request
     # constants to the devices at split time (GEMV's x, BS's array, ...) —
@@ -471,10 +495,11 @@ def run_pipelined_ranked(grid, workload: ChunkedWorkload,
     metas = [[None] * n_req for _ in range(n_ranks)]
     entries: list = [None] * n_req
     streams: list[list] = [[] for _ in range(n_ranks)]
+    # bucket[0] is this thread's: rank 0's pipeline, and the splits and
+    # merges, which stamp the records' device_wait_s and host_self_s
     bucket = [[_Buckets() for _ in range(n_req)] for _ in range(n_ranks)]
     t_first = [[0.0] * n_req for _ in range(n_ranks)]
     t_retired = [[0.0] * n_req for _ in range(n_ranks)]
-    tr0 = get_tracer()
 
     t0 = time.perf_counter()
     total = n_ranks * n_chunks
@@ -492,9 +517,7 @@ def run_pipelined_ranked(grid, workload: ChunkedWorkload,
                 rank_parts[r] = _rank_worker(grid.rank_view(r), workload,
                                              metas[r], streams[r], bucket[r],
                                              t_first[r], t_retired[r],
-                                             entries=entries,
-                                             requests=requests,
-                                             split_total=total)
+                                             entries, requests, total, rids)
         except BaseException as e:           # noqa: BLE001 — re-raised below
             errors[r] = e
 
@@ -502,36 +525,27 @@ def run_pipelined_ranked(grid, workload: ChunkedWorkload,
         for i, args in enumerate(requests):
             per = n_chunks
             ts = time.perf_counter()
-            ent, hit = (cache.acquire(workload, args,
-                                      (grid.n_banks, n_ranks, total))
-                        if use_cache else (None, False))
-            entries[i] = ent
-            for r in range(n_ranks):
-                metas[r][i], chunks = _split_with_cache(
-                    grid.rank_view(r), workload, args, total, ent, rank=r,
-                    hit=hit)
-                per = -(-len(chunks) // n_ranks)  # contiguous rank blocks
-                streams[r].extend(
-                    (i, g, chunks[g])
-                    for g in range(r * per,
-                                   min((r + 1) * per, len(chunks))))
-            if (ent is not None and hit and not ent.chunk_resident
-                    and tr0.enabled):
-                # meta-resident hit: the skipped per-rank broadcasts happened
-                # at split time, so the cached span lands here (host track)
-                tr0.emit("scatter:cached", "cpu_dpu", ts,
-                         time.perf_counter(), track="host",
-                         workload=workload.name, req=_req_id(records, i),
-                         bytes=ent.nbytes, fingerprint=ent.fingerprint)
+            with span("split", "split", req=rids[i], workload=workload.name):
+                ent, hit = (cache.acquire(workload, args,
+                                          (grid.n_banks, n_ranks, total))
+                            if use_cache else (None, False))
+                entries[i] = ent
+                with _meta_cached_span(workload, ent, hit, rids[i]):
+                    for r in range(n_ranks):
+                        metas[r][i], chunks = _split_with_cache(
+                            grid.rank_view(r), workload, args, total, ent,
+                            rank=r, hit=hit)
+                        per = -(-len(chunks) // n_ranks)  # contiguous blocks
+                        streams[r].extend(
+                            (i, g, chunks[g])
+                            for g in range(r * per,
+                                           min((r + 1) * per, len(chunks))))
+            bucket[0][i].add(None, ts)
             if records is not None:
                 # n_chunks is the per-pipeline depth (matches the flat path
                 # and the plan's value); total chunks = n_chunks * n_ranks
-                records[i].n_chunks = per
+                _stamp_split(records[i], per, hit, plan)
                 records[i].n_ranks = n_ranks
-                records[i].cache_hit = hit
-                if (hit and plan is not None
-                        and getattr(plan, "warm_predicted_overlap", 0.0)):
-                    records[i].predicted_overlap = plan.warm_predicted_overlap
 
         threads = [threading.Thread(target=worker, args=(r,),
                                     name=f"pim-rank-{r}", daemon=True)
@@ -556,13 +570,10 @@ def run_pipelined_ranked(grid, workload: ChunkedWorkload,
     for i in range(n_req):
         parts = sorted(p for ps in rank_parts for p in ps.get(i, ()))
         ts = time.perf_counter()
-        results[i] = workload.merge(rep, metas[0][i], [p for _, p in parts])
-        t_merged = time.perf_counter()
-        merge_dt = t_merged - ts
-        if tr.enabled:
-            tr.emit("merge", "inter_dpu", ts, t_merged, track="host",
-                    workload=workload.name, req=_req_id(records, i),
-                    ranks=n_ranks)
+        with span("merge", "inter_dpu", req=rids[i], workload=workload.name):
+            results[i] = workload.merge(rep, metas[0][i],
+                                        [p for _, p in parts])
+        merge_dt = bucket[0][i].add(None, ts) - ts
         times = _phases()
         for r in range(n_ranks):                 # host-observed, summed over
             for k in dataclasses.fields(times):  # the rank threads
@@ -580,9 +591,12 @@ def run_pipelined_ranked(grid, workload: ChunkedWorkload,
         t_done = (retired or time.perf_counter()) + merge_dt
         makespans[i] = t_done - t_start
         if records is not None:
+            own = bucket[0][i]
             records[i].t_start = t_start
             records[i].t_finish = t_done
             records[i].phases = times
+            records[i].device_wait_s = own.wait
+            records[i].host_self_s = own.busy - own.wait
     if _full:
         return results, makespans, phases
     return results

@@ -60,6 +60,13 @@ Every request carries a :class:`~repro.runtime.telemetry.RequestRecord`;
 completed records land in the scheduler's :class:`Telemetry` sink, and a
 ``serve`` span per completion lands on the request's ``tenant-<name>``
 trace track, so Perfetto shows one lane per tenant (DESIGN.md §11).
+
+Spans (``runtime/trace.py``), each tagged with a request's id: ``submit``
+on the client's thread (record and admit); on the serving thread ``wait``
+(blocked on an empty queue, tagged with the request that ended it),
+``pop`` (:meth:`PimScheduler._pop_batch`), ``batch`` (one per dispatch,
+around the pipeline's spans) and ``fulfill`` (telemetry and the future,
+per request).
 """
 from __future__ import annotations
 
@@ -81,7 +88,7 @@ from .qos import (DEFAULT_TENANT, NO_DEADLINE, DeadlineExpired, QueueFull,
 from .resident import unwrap_handles
 from .straggler import StepMonitor, StragglerConfig
 from .telemetry import RequestRecord, Telemetry, now
-from .trace import get_tracer
+from .trace import get_tracer, span
 
 if TYPE_CHECKING:  # annotation-only: importing repro.prim pulls the suite
     from repro.prim import common
@@ -213,6 +220,7 @@ class PimScheduler:
         self._step = itertools.count()
         self._seq = itertools.count()
         self._batch_seq = itertools.count()
+        self._waker: int | None = None          # made the queue non-empty
         self._cv = threading.Condition()
         self._thread: threading.Thread | None = None
         self._stopping = False
@@ -314,6 +322,8 @@ class PimScheduler:
                 self._cv.wait()
         t.activate(self._vclock)                # no credit for idle time
         t.submitted += 1
+        if not self._depth:
+            self._waker = req.record.request_id
         heapq.heappush(t.queue, (self._key(req), req))
         self._depth += 1
 
@@ -327,12 +337,14 @@ class PimScheduler:
         if workload not in self.workloads and workload not in self.serialized:
             raise KeyError(f"unknown workload {workload!r}; have "
                            f"{sorted(self.workloads) + sorted(self.serialized)}")
-        rec = self.make_record(workload, args, opts)
-        req = PimRequest(workload, args, opts, rec)
-        with self._cv:
-            self._admit(req)                    # may raise QueueFull / block
-            depth = self._depth
-            self._cv.notify()
+        with span("submit", "queue", workload=workload) as sp:
+            rec = self.make_record(workload, args, opts)
+            sp.tag(req=rec.request_id)
+            req = PimRequest(workload, args, opts, rec)
+            with self._cv:
+                self._admit(req)                # may raise QueueFull / block
+                depth = self._depth
+                self._cv.notify()
         m = self.telemetry.metrics            # live counters (DESIGN.md §11)
         m.inc("submitted")
         m.observe("queue_depth", depth, bounds=range(1, 257))
@@ -402,37 +414,35 @@ class PimScheduler:
         tenants, so fair-share accounting stays per-batch-exact.  Returns
         ``[]`` only when nothing dispatchable is queued.  Caller holds
         ``_cv``."""
-        tr = get_tracer()
-        t0 = now() if tr.enabled else 0.0
-        tenant = self._select_tenant()
-        if tenant is None:
-            return []
-        _, head = heapq.heappop(tenant.queue)
-        self._depth -= 1
-        plan = self.plans.get(head.workload)
-        max_requests = (plan.max_batch_requests if plan is not None
-                        else self.max_batch_requests)
-        batch, nbytes = [head], head.record.bytes_in
-        t_now = now()
-        while tenant.queue:
-            if self._expire_head(tenant, t_now):
-                continue                 # dropping never reorders survivors
-            _, req = tenant.queue[0]
-            if (req.workload != head.workload
-                    or len(batch) >= max_requests
-                    or nbytes + req.record.bytes_in > self.max_batch_bytes):
-                break
-            heapq.heappop(tenant.queue)
+        with span("pop", "sched") as sp:
+            tenant = self._select_tenant()
+            if tenant is None:
+                return []
+            _, head = heapq.heappop(tenant.queue)
             self._depth -= 1
-            batch.append(req)
-            nbytes += req.record.bytes_in
-        if self.max_queue_depth is not None:
-            self._cv.notify_all()        # wake submitters blocked on depth
-        if tr.enabled:
-            tr.emit("batch_form", "sched", t0, now(), track="scheduler",
-                    workload=head.workload, tenant=tenant.name,
-                    requests=len(batch), bytes=nbytes, queued=self._depth)
-        return batch
+            plan = self.plans.get(head.workload)
+            max_requests = (plan.max_batch_requests if plan is not None
+                            else self.max_batch_requests)
+            batch, nbytes = [head], head.record.bytes_in
+            t_now = now()
+            while tenant.queue:
+                if self._expire_head(tenant, t_now):
+                    continue             # dropping never reorders survivors
+                _, req = tenant.queue[0]
+                if (req.workload != head.workload
+                        or len(batch) >= max_requests
+                        or nbytes + req.record.bytes_in
+                        > self.max_batch_bytes):
+                    break
+                heapq.heappop(tenant.queue)
+                self._depth -= 1
+                batch.append(req)
+                nbytes += req.record.bytes_in
+            if self.max_queue_depth is not None:
+                self._cv.notify_all()    # wake submitters blocked on depth
+            sp.tag(req=head.record.request_id, requests=len(batch),
+                   bytes=nbytes)
+            return batch
 
     # -- elastic rank placement (DESIGN.md §13) -------------------------------
 
@@ -473,6 +483,18 @@ class PimScheduler:
 
     # -- execution ------------------------------------------------------------
 
+    def _complete(self, req: PimRequest, bid: int, result) -> None:
+        """Record a completed request and hand its result to the caller."""
+        with span("fulfill", "sched", req=req.record.request_id, batch=bid):
+            self.telemetry.record(req.record)
+            req._fulfill(result=result)
+        tr = get_tracer()
+        if tr.enabled:
+            rec = req.record
+            tr.emit("serve", "session", rec.t_submit, rec.t_finish,
+                    track=f"tenant-{rec.tenant}", workload=rec.workload,
+                    req=rec.request_id, tenant=rec.tenant, **_span_tags(rec))
+
     def _run_serialized(self, batch: Sequence[PimRequest], bid: int) -> None:
         """Serialized-only fallback (NW/BFS): run each request's faithful
         ``pim()`` back-to-back — no chunk overlap exists to exploit — but
@@ -496,16 +518,12 @@ class PimScheduler:
             rec.phases = times
             rec.bytes_out = (result.nbytes
                              if isinstance(result, np.ndarray) else 0)
-            self.telemetry.record(rec)
-            req._fulfill(result=result)
-            if tr.enabled:
-                tr.emit("serve", "session", rec.t_submit, rec.t_finish,
-                        track=f"tenant-{rec.tenant}", workload=rec.workload,
-                        req=rec.request_id, tenant=rec.tenant,
-                        **_span_tags(rec))
+            self._complete(req, bid, result)
 
-    def _run_batch(self, batch: Sequence[PimRequest]) -> None:
-        bid = next(self._batch_seq)
+    def _run_batch(self, batch: Sequence[PimRequest],
+                   bid: int | None = None) -> None:
+        if bid is None:
+            bid = next(self._batch_seq)
         tr = get_tracer()
         if tr.enabled:
             # queue wait became service: emit the wait interval per request
@@ -544,13 +562,7 @@ class PimScheduler:
             return
         for req, rec, res in zip(batch, records, results):
             rec.bytes_out = res.nbytes if isinstance(res, np.ndarray) else 0
-            self.telemetry.record(rec)
-            req._fulfill(result=res)
-            if tr.enabled:
-                tr.emit("serve", "session", rec.t_submit, rec.t_finish,
-                        track=f"tenant-{rec.tenant}", workload=rec.workload,
-                        req=rec.request_id, tenant=rec.tenant,
-                        **_span_tags(rec))
+            self._complete(req, bid, res)
 
     def _dispatch(self, batch: Sequence[PimRequest]) -> None:
         """Run one popped batch and settle the fair-share bill: the
@@ -558,37 +570,35 @@ class PimScheduler:
         its weight, and the batch's service feeds the straggler monitor
         (a flagged batch halves the elastic rank cap, a healthy one
         relaxes it)."""
-        mon = self._monitor(batch[0].workload)
-        flagged_before = len(mon.flagged) if mon is not None else 0
-        if mon is not None:
-            mon.start_step()
-        t0 = now()
-        self._run_batch(batch)
-        service = now() - t0
-        if mon is not None:
-            mon.end_step(next(self._step))
-            if self.allocator is not None \
-                    and len(mon.flagged) == flagged_before:
-                self.allocator.relax()
-        with self._cv:
-            t = self._tenants.get(batch[0].options.tenant)
-            if t is not None:
-                self._vclock = max(self._vclock, t.charge(service))
+        bid = next(self._batch_seq)
+        with span("batch", "sched", req=batch[0].record.request_id, batch=bid,
+                  workload=batch[0].workload, requests=len(batch)):
+            mon = self._monitor(batch[0].workload)
+            flagged_before = len(mon.flagged) if mon is not None else 0
+            if mon is not None:
+                mon.start_step()
+            t0 = now()
+            self._run_batch(batch, bid)
+            service = now() - t0
+            if mon is not None:
+                mon.end_step(next(self._step))
+                if self.allocator is not None \
+                        and len(mon.flagged) == flagged_before:
+                    self.allocator.relax()
+            with self._cv:
+                t = self._tenants.get(batch[0].options.tenant)
+                if t is not None:
+                    self._vclock = max(self._vclock, t.charge(service))
 
     def drain(self) -> int:
         """Process queued requests in the calling thread until empty.
         Returns the number of requests completed (expired requests are
         dropped, not run, and do not count)."""
-        tr = get_tracer()
-        t0 = now() if tr.enabled else 0.0
         done = 0
         while True:
             with self._cv:
-                batch = self._pop_batch()
+                batch = self._pop_batch() if self._depth else []
                 if not batch:
-                    if tr.enabled and done:
-                        tr.emit("drain", "sched", t0, now(),
-                                track="scheduler", requests=done)
                     return done
             self._dispatch(batch)
             done += len(batch)
@@ -604,12 +614,16 @@ class PimScheduler:
         def loop():
             while True:
                 with self._cv:
-                    while not self._depth and not self._stopping:
-                        self._cv.wait()
+                    if not self._depth and not self._stopping:
+                        with span("wait", "sched") as sp:
+                            while not self._depth and not self._stopping:
+                                self._cv.wait()
+                            if self._depth:
+                                sp.tag(req=self._waker)
+                    if not self._depth:  # stopping, nothing left queued
+                        return
                     batch = self._pop_batch()
                     if not batch:
-                        if self._stopping:
-                            return
                         continue         # whole backlog expired: re-wait
                 self._dispatch(batch)
 

@@ -7,10 +7,13 @@ baseline, and achieved CPU↔bank bandwidth.  Benchmarks render both views —
 the paper's serialized bars and the pipelined bars — from the same records.
 
 Phase accounting under overlap is host-observed: ``cpu_dpu`` is time spent
-issuing scatters, ``dpu`` time spent dispatching/awaiting bank-local compute,
-``dpu_cpu`` time blocked in retrieves, ``inter_dpu`` host-side merge time.
-The buckets sum to roughly the makespan; hidden (overlapped) device time by
-construction does not appear — that is the point.
+issuing scatters, ``dpu`` time spent enqueueing bank-local compute (the
+asynchronous launch, not the device's time), ``dpu_cpu`` time blocked on
+chunks and copying them out, ``inter_dpu`` host-side merge time.  Hidden
+(overlapped) device time by construction does not appear — that is the
+point.  ``device_wait_s`` and ``host_self_s`` split the same intervals,
+and the split before them, into the serving thread's time blocked on the
+device and its own time on the request.
 
 Serving-hardened (DESIGN.md §11): completed records land in a **bounded
 ring buffer** (``max_records``, default 64k) so a long-running ``submit()``
@@ -86,6 +89,13 @@ class RequestRecord:
     t_start: float = 0.0
     t_finish: float = 0.0
     phases: "PhaseTimes" = dataclasses.field(default_factory=_phases)
+    #: the serving thread's seconds blocked on this request's chunks (its
+    #: ``device_wait`` spans, runtime/pipeline.py)
+    device_wait_s: float = 0.0
+    #: the serving thread's seconds inside this request's pipeline spans
+    #: (split, scatter, launch, device_wait, copy_out, merge), less
+    #: ``device_wait_s``: the host's own time on the request
+    host_self_s: float = 0.0
     serialized_s: float = 0.0   # optional: measured pim() baseline time
     predicted_overlap: float = 0.0   # autotune plan's promise (0 = untuned)
     #: cost-model per-stage seconds (cpu_dpu/dpu/dpu_cpu) stamped from the
@@ -147,6 +157,8 @@ class RequestRecord:
                 "cpu_dpu_s": self.phases.cpu_dpu, "dpu_s": self.phases.dpu,
                 "inter_dpu_s": self.phases.inter_dpu,
                 "dpu_cpu_s": self.phases.dpu_cpu,
+                "device_wait_s": self.device_wait_s,
+                "host_self_s": self.host_self_s,
                 "overlap_speedup": self.overlap_speedup,
                 "tuned": self.tuned, "cache_hit": self.cache_hit,
                 "predicted_overlap": self.predicted_overlap,

@@ -5,19 +5,32 @@ Inter-DPU / DPU-CPU phase bars — but host-observed per-request sums
 (``runtime/telemetry.py``) cannot show *where inside* a pipelined,
 rank-sharded request time goes.  This module records **spans**: named,
 categorized ``[t0, t1)`` intervals tagged with request / workload / rank /
-chunk / bytes, grouped onto **tracks** (one per rank pipeline, plus host /
-scheduler / session), and exports them as Chrome ``trace_event`` JSON that
-loads directly in `ui.perfetto.dev <https://ui.perfetto.dev>`_ or
-``chrome://tracing``.
+chunk / bytes, and writes each to two sinks through one call,
+:func:`span`:
+
+* **the profiler** — while a ``jax.profiler`` session records, a span is a
+  ``TraceAnnotation`` named ``pim.<name>`` with its tags as metadata, on
+  the thread that does the work and on the same clock as the device
+  planes of the ``.xplane.pb`` (``bench/span_reduce.py`` names the
+  device's idle time by them);
+* **the ring buffer** of the active :class:`Tracer`, grouped onto
+  **tracks** (one per rank pipeline, plus host / scheduler / session),
+  exported as Chrome ``trace_event`` JSON that loads directly in
+  `ui.perfetto.dev <https://ui.perfetto.dev>`_ or ``chrome://tracing``.
+
+Spans of one request share its ``req`` tag (``RequestRecord.request_id``)
+from the client's ``submit`` to the scheduler's ``fulfill``.  Intervals
+measured elsewhere (``queue_wait`` and ``serve``, stamped from another
+thread's timestamps) go to the ring buffer only, through
+:meth:`Tracer.emit`.
 
 Design constraints (the follow-up tooling argument of arXiv:2110.01709 /
 arXiv:2205.14647 — adoption hinges on profiling built *into* the runtime):
 
-* **off by default, near-zero disabled overhead** — the module-level active
-  tracer is a :data:`NULL_TRACER` whose ``span()`` returns one shared no-op
-  context manager (no allocation) and whose ``emit()`` is a single
-  attribute-check away from a no-op.  Hot paths guard with
-  ``if tr.enabled:`` so tag dicts are never even built when tracing is off;
+* **off by default, near-zero disabled overhead** — with no profiler
+  recording and the module-level active tracer the :data:`NULL_TRACER`,
+  :func:`span` returns one shared no-op context manager: no allocation,
+  no tag dict (its tags are named parameters, not ``**kwargs``);
 * **bounded memory** — spans land in a ring buffer (``max_spans``), so a
   long-serving session cannot leak; the drop count is reported in the
   export's metadata;
@@ -35,7 +48,7 @@ installs a :class:`Tracer` as the active one, and
 critical-path / overlap-efficiency summary from the same file.
 
 Residency spans (DESIGN.md §12): a chunk served from the resident-operand
-cache emits ``scatter:cached`` (category ``cpu_dpu``, tagged with the
+cache emits ``scatter_cached`` (category ``cpu_dpu``, tagged with the
 entry's ``fingerprint`` and the bytes the skipped push would have moved)
 in place of the ``scatter`` span, so warm traffic is visually distinct on
 every pipeline track and ``tools/trace_view.py`` can report the cached-
@@ -51,9 +64,15 @@ import threading
 import time
 from typing import Mapping
 
+from jax.profiler import TraceAnnotation
+
 #: span categories, matching the paper's phase naming (telemetry docstring)
-CATEGORIES = ("cpu_dpu", "dpu", "dpu_cpu", "inter_dpu",
+CATEGORIES = ("split", "cpu_dpu", "dpu", "dpu_cpu", "inter_dpu",
               "transfer", "queue", "sched", "session")
+
+#: True while a ``jax.profiler`` session records (one atomic read in C++,
+#: about 30 ns)
+profiling = TraceAnnotation.is_enabled
 
 #: default ring-buffer capacity (spans, not bytes); a span is ~200 B, so the
 #: default bounds tracer memory at ~50 MB worst case
@@ -89,6 +108,12 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def tag(self, req=None, requests=None, bytes=None):
+        pass
+
+    def stamp(self, t0, t1):
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
@@ -114,30 +139,6 @@ class NullTracer:
 
 
 NULL_TRACER = NullTracer()
-
-
-class _SpanCtx:
-    """Context manager recording one span on ``__exit__``."""
-
-    __slots__ = ("_tracer", "_name", "_cat", "_track", "_args", "_t0")
-
-    def __init__(self, tracer, name, cat, track, args):
-        self._tracer = tracer
-        self._name = name
-        self._cat = cat
-        self._track = track
-        self._args = args
-        self._t0 = 0.0
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self._tracer.emit(self._name, self._cat, self._t0,
-                          time.perf_counter(), track=self._track,
-                          **(self._args or {}))
-        return False
 
 
 class Tracer:
@@ -178,16 +179,17 @@ class Tracer:
         return name
 
     def span(self, name: str, cat: str = "", track: str | None = None,
-             **args) -> _SpanCtx:
+             **args) -> "_LayerSpan":
         """Context manager: ``with tracer.span("merge", cat="inter_dpu",
-        workload="VA"): ...`` records the wrapped interval."""
-        return _SpanCtx(self, name, cat, track, args or None)
+        workload="VA"): ...`` records the wrapped interval in this tracer
+        (and, like :func:`span`, in a recording profiler)."""
+        return _LayerSpan(name, cat, track, args, self)
 
     def emit(self, name: str, cat: str, t0: float, t1: float,
              track: str | None = None, **args) -> None:
-        """Record an interval measured elsewhere — the hot-path form: the
-        pipeline already takes the timestamps for its phase buckets, so
-        tracing rides them instead of timing twice."""
+        """Record an interval measured elsewhere, such as a request's queue
+        wait stamped from its submitter's clock (ring buffer only: the
+        profiler takes no interval after the fact)."""
         if len(self.spans) == self.spans.maxlen:
             self.dropped += 1
         self.spans.append(Span(name, cat, t0, t1,
@@ -266,10 +268,9 @@ class Tracer:
 
 # -- module-level active tracer ----------------------------------------------
 #
-# The runtime's hot paths (core/transfer.py, runtime/pipeline.py,
-# runtime/scheduler.py) fetch the active tracer through get_tracer() — a
-# plain module global, read without locking (rebinding is atomic under the
-# GIL).  The session façade installs/uninstalls it; one traced session at a
+# The runtime's hot paths reach the active tracer through span() below
+# (emit() callers through get_tracer()) — a plain module global, read
+# without locking (rebinding is atomic under the GIL).  The session façade installs/uninstalls it; one traced session at a
 # time is the supported shape (last install wins, uninstall restores the
 # previous tracer).
 
@@ -288,3 +289,77 @@ def set_tracer(tracer: Tracer | NullTracer) -> Tracer | NullTracer:
     prev = _ACTIVE
     _ACTIVE = tracer
     return prev
+
+
+# -- one span call, two sinks -------------------------------------------------
+
+class _LayerSpan:
+    """Context manager of :func:`span`: a ``pim.<name>`` profiler annotation
+    while a profiler session records, and a ring-buffer span while the
+    tracer it was made under is enabled."""
+
+    __slots__ = ("_name", "_cat", "_track", "_tags", "_tracer", "_ann",
+                 "_t0", "_t1")
+
+    def __init__(self, name, cat, track, tags, tracer):
+        self._name = name
+        self._cat = cat
+        self._track = track
+        self._tags = tags
+        self._tracer = tracer
+        self._ann = None
+        self._t1 = None
+
+    def __enter__(self):
+        if profiling():
+            self._ann = TraceAnnotation("pim." + self._name, **self._tags)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def tag(self, req=None, requests=None, bytes=None):
+        """Tags known only once the span is open (the request a ``wait``
+        ended with, the head of a popped batch, the bytes copied out)."""
+        more = {k: v for k, v in (("req", req), ("requests", requests),
+                                  ("bytes", bytes)) if v is not None}
+        self._tags.update(more)
+        if self._ann is not None and more:
+            self._ann.set_metadata(**more)
+
+    def stamp(self, t0, t1):
+        """Give the ring-buffer span the caller's own clock reads, so that
+        it and a record the caller stamps from them agree exactly."""
+        self._t0, self._t1 = t0, t1
+
+    def __exit__(self, *exc):
+        if self._tracer.enabled:
+            t1 = self._t1 if self._t1 is not None else time.perf_counter()
+            self._tracer.emit(self._name, self._cat, self._t0, t1,
+                              track=self._track, **self._tags)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+def span(name: str, cat: str = "", *, req=None, batch=None, chunk=None,
+         workload=None, bytes=None, requests=None, fingerprint=None,
+         track=None):
+    """``with span("scatter", "cpu_dpu", req=7, chunk=0): ...`` — one span
+    to both sinks (module docstring).  With no profiler recording and the
+    tracer off it returns the shared :data:`NULL_SPAN` and builds nothing.
+    ``track`` names the ring-buffer track (default: the thread's)."""
+    tracer = _ACTIVE
+    if not tracer.enabled and not profiling():
+        return NULL_SPAN
+    tags = {k: v for k, v in (("req", req), ("batch", batch),
+                              ("chunk", chunk), ("workload", workload),
+                              ("bytes", bytes), ("requests", requests),
+                              ("fingerprint", fingerprint))
+            if v is not None}
+    return _LayerSpan(name, cat, track, tags, tracer)
+
+
+def tracing() -> bool:
+    """True when :func:`span` would record: a profiler session or the
+    active tracer is on (guards tags that cost something to compute)."""
+    return _ACTIVE.enabled or profiling()

@@ -128,7 +128,7 @@ def test_warm_steps_emit_zero_weight_scatter_bytes(decode_run):
     # every decode step serves weights from the banks — zero scatter spans
     assert decode_run.n_scatter_pin == 0
     assert not _spans(s, "scatter")
-    cached = _spans(s, "scatter:cached")
+    cached = _spans(s, "scatter_cached")
     assert cached, "warm steps should serve weights from the banks"
     assert sum(sp.args["bytes"] for sp in cached) > 0
     cs = s.stats()["cache"]
@@ -151,7 +151,7 @@ def test_cold_engine_rescatters_weights_every_step():
         s.close()
         set_tracer(NULL_TRACER)
     assert out.shape == (1, 4)
-    assert not _spans(s, "scatter:cached")
+    assert not _spans(s, "scatter_cached")
     steps = len(eng.steps)
     weight_nbytes = sum(
         sum(a.nbytes for a in h.value.values())
@@ -205,7 +205,7 @@ out = eng.generate(np.asarray(prompt), 6)
 np.testing.assert_array_equal(out, ref)
 n_scatter = sum(1 for sp in s.tracer.spans if sp.name == "scatter")
 assert n_scatter == 0, n_scatter                   # decode pushed no weights
-assert any(sp.name == "scatter:cached" for sp in s.tracer.spans)
+assert any(sp.name == "scatter_cached" for sp in s.tracer.spans)
 recs = [r for r in s.telemetry.records if r.tags.get("proj")]
 assert recs and all(r.n_ranks == 2 for r in recs)
 s.close()

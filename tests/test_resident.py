@@ -16,7 +16,7 @@ Covers the cache's correctness contract end to end:
   exactly once (trace-span counted), and close() mid-flight drains every
   future and releases every resident buffer;
 * rank-aware residency — on a 2x4 RankGrid the warm run pushes nothing
-  (zero new ``scatter`` spans, one ``scatter:cached`` per chunk), asserted
+  (zero new ``scatter`` spans, one ``scatter_cached`` per chunk), asserted
   from the trace (subprocess).
 """
 import os
@@ -452,7 +452,7 @@ def test_handles_nested_inside_pytree_operands_unwrap(bank_grid):
 def test_concurrent_submits_same_fingerprint_scatter_exactly_once(bank_grid):
     """N threads submit the same operand to a serving session: every chunk
     must be pushed exactly once (counted from trace spans), every other
-    serve must be a ``scatter:cached``, and every result must match ref."""
+    serve must be a ``scatter_cached``, and every result must match ref."""
     entry, (A, x) = _gemv_args(seed=8)
     ref_out = entry.ref(A, x)
     n_threads = 4
@@ -480,9 +480,9 @@ def test_concurrent_submits_same_fingerprint_scatter_exactly_once(bank_grid):
     assert len(depths) == 1
     n = depths.pop()
     assert names.count("scatter") == n, (names.count("scatter"), n)
-    assert names.count("scatter:cached") == (n_threads - 1) * n
+    assert names.count("scatter_cached") == (n_threads - 1) * n
     fps = {sp.args["fingerprint"] for sp in s.tracer.spans
-           if sp.name == "scatter:cached"}
+           if sp.name == "scatter_cached"}
     assert len(fps) == 1
 
 
@@ -594,12 +594,12 @@ n_cold = sum(1 for sp in s.tracer.spans if sp.name == "scatter")
 assert n_cold >= 2, n_cold
 warm = s.run("GEMV", *args)
 n_scatter = sum(1 for sp in s.tracer.spans if sp.name == "scatter")
-n_cached = sum(1 for sp in s.tracer.spans if sp.name == "scatter:cached")
+n_cached = sum(1 for sp in s.tracer.spans if sp.name == "scatter_cached")
 assert n_scatter == n_cold, (n_scatter, n_cold)   # warm run pushed NOTHING
 assert n_cached == n_cold, (n_cached, n_cold)     # every warm chunk served
 fps = set()
 for sp in s.tracer.spans:
-    if sp.name == "scatter:cached":
+    if sp.name == "scatter_cached":
         assert sp.cat == "cpu_dpu", sp.cat
         fps.add(sp.args["fingerprint"])
 assert len(fps) == 1, fps

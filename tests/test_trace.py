@@ -110,7 +110,7 @@ def test_span_dur_clamps_negative():
 
 def test_export_is_valid_trace_event_json(tmp_path):
     tr = Tracer()
-    tr.emit("compute", "dpu", tr.t_origin + 0.001, tr.t_origin + 0.003,
+    tr.emit("launch", "dpu", tr.t_origin + 0.001, tr.t_origin + 0.003,
             track="rank-1", req=0, chunk=2)
     tr.emit("scatter", "cpu_dpu", tr.t_origin, tr.t_origin + 0.001,
             track="rank-0")
@@ -130,10 +130,10 @@ def test_export_is_valid_trace_event_json(tmp_path):
     assert tids["host"] < tids["rank-0"] < tids["rank-1"]
     for e in spans:
         assert e["ts"] >= 0 and e["dur"] >= 0 and e["tid"] in tids.values()
-    compute = next(e for e in spans if e["name"] == "compute")
-    assert compute["cat"] == "dpu"
-    assert compute["args"] == {"req": 0, "chunk": 2}
-    assert compute["dur"] == pytest.approx(2000.0, rel=0.01)   # µs
+    launch = next(e for e in spans if e["name"] == "launch")
+    assert launch["cat"] == "dpu"
+    assert launch["args"] == {"req": 0, "chunk": 2}
+    assert launch["dur"] == pytest.approx(2000.0, rel=0.01)   # µs
 
 
 # -- session lifecycle --------------------------------------------------------
@@ -150,7 +150,8 @@ def test_session_trace_lifecycle(bank_grid, rng, tmp_path):
     names = {sp.name for sp in s.tracer.spans}
     cats = {sp.cat for sp in s.tracer.spans}
     assert "run:VA" in names and {"session", "queue", "sched"} <= cats
-    assert {"scatter", "compute", "retrieve", "merge"} <= names
+    assert {"split", "scatter", "launch", "device_wait", "copy_out",
+            "merge"} <= names
     st = s.stats()
     assert st["trace"]["spans"] == len(s.tracer.spans)
     path = s.trace_export(tmp_path / "va.json")
@@ -237,7 +238,7 @@ def test_trace_view_summary_and_top(tmp_path):
     for k in range(4):                  # overlapped 2-stage pipeline shape
         tr.emit("scatter", "cpu_dpu", t0 + k * 0.01, t0 + k * 0.01 + 0.004,
                 track="rank-0")
-        tr.emit("compute", "dpu", t0 + k * 0.01 + 0.004,
+        tr.emit("launch", "dpu", t0 + k * 0.01 + 0.004,
                 t0 + (k + 1) * 0.01, track="rank-0")
     path = tr.export(tmp_path / "v.json")
     spans, tracks = trace_view.split_events(trace_view.load_events(path))
@@ -276,12 +277,14 @@ for e in doc["traceEvents"]:
 for rank in ("rank-0", "rank-1"):
     evs = by_track[tids[rank]]
     names = {{e["name"] for e in evs}}
-    assert {{"scatter", "compute", "retrieve"}} <= names, (rank, names)
+    assert {{"scatter", "launch", "device_wait", "copy_out"}} <= names, \
+        (rank, names)
     assert all("chunk" in e["args"] for e in evs), rank
 # within a rank track the spans are sequential host-observed windows
-# (scatter = async enqueue, compute = dispatch+await); the concurrency the
-# trace must SHOW is *across* tracks — rank-0 and rank-1 pipelines busy at
-# the same time (the paper's rank-parallel transfers, DESIGN.md §10)
+# (scatter and launch = async enqueues, device_wait = await); the
+# concurrency the trace must SHOW is *across* tracks — rank-0 and rank-1
+# pipelines busy at the same time (the paper's rank-parallel transfers,
+# DESIGN.md §10)
 r0, r1 = by_track[tids["rank-0"]], by_track[tids["rank-1"]]
 overlapped = any(
     a["ts"] < b["ts"] + b["dur"] and b["ts"] < a["ts"] + a["dur"]
@@ -302,3 +305,130 @@ def test_ranked_tracks_8_devices(tmp_path):
         capture_output=True, text=True, env=env, timeout=600)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "TRACE-RANKED-OK" in out.stdout
+
+
+# -- one span call, two sinks: the profiler's clock ---------------------------
+
+def test_span_with_no_sink_is_null_span():
+    from repro.runtime.trace import profiling, span, tracing
+    assert not profiling() and not tracing()
+    assert span("scatter", "cpu_dpu", req=3, chunk=0, bytes=64) is NULL_SPAN
+    with span("wait") as sp:
+        sp.tag(req=1)                       # no-op, like the span itself
+        sp.stamp(0.0, 1.0)
+
+
+def test_span_records_to_the_ring_buffer_with_tags():
+    from repro.runtime.trace import span
+    tr = Tracer()
+    prev = set_tracer(tr)
+    try:
+        with span("pop", "sched", workload="VA") as sp:
+            sp.tag(req=5, requests=2)
+        with span("copy", "transfer") as sp:
+            sp.stamp(tr.t_origin, tr.t_origin + 0.25)
+    finally:
+        set_tracer(prev)
+    pop, copy = tr.spans
+    assert (pop.name, pop.cat, pop.track) == ("pop", "sched", "host")
+    assert pop.args == {"workload": "VA", "req": 5, "requests": 2}
+    assert copy.dur == pytest.approx(0.25)
+
+
+def _profiled_lines(path) -> list:
+    """Each host thread's ``pim.*`` events as (name, start, end, stats)."""
+    from jax.profiler import ProfileData
+    lines = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats)) for e in line.events
+                   if e.name.startswith("pim.")]
+            if evs:
+                lines.append(evs)
+    return lines
+
+
+@pytest.mark.parametrize("workload", ["GEMV", "VA"])
+def test_profiler_spans_of_one_served_request(bank_grid, rng, tmp_path,
+                                              workload):
+    """Under a profiler session alone (no tracer), one request served by the
+    scheduler thread leaves nested ``pim.*`` spans that all carry its id,
+    and the client's ``pim.submit`` carries the same id."""
+    import jax
+
+    from repro import pim
+
+    entry = pim.registry()[workload]
+    args = entry.make_args(rng, 1)
+    s = pim.PimSession(grid=bank_grid, trace=False).start()
+    try:
+        s.run(workload, *args)              # compile outside the profile
+        with jax.profiler.trace(str(tmp_path)):
+            out = s.run(workload, *args)
+    finally:
+        s.close()
+    entry.compare(out, entry.ref(*args))
+    (rec,) = [r for r in s.telemetry.records][-1:]
+    lines = _profiled_lines(next(tmp_path.rglob("*.xplane.pb")))
+    (serving,) = [ln for ln in lines
+                  if any(e[0] == "pim.batch" for e in ln)]
+    submits = [e[3]["req"] for ln in lines for e in ln
+               if e[0] == "pim.submit" and ln is not serving]
+    assert submits == [rec.request_id]
+    names = {e[0] for e in serving}
+    assert {"pim.pop", "pim.batch", "pim.split", "pim.launch",
+            "pim.device_wait", "pim.copy_out", "pim.merge",
+            "pim.fulfill"} <= names
+    assert names & {"pim.scatter", "pim.scatter_cached"}
+    assert {e[3].get("req") for e in serving} == {rec.request_id}
+    (batch,) = [e for e in serving if e[0] == "pim.batch"]
+    for name, t0, t1, _ in serving:         # properly nested, one thread
+        if name not in ("pim.batch", "pim.pop", "pim.wait"):
+            assert batch[1] <= t0 <= t1 <= batch[2], name
+        for other in serving:
+            disjoint = t1 <= other[1] or other[2] <= t0
+            inside = other[1] <= t0 and t1 <= other[2]
+            outside = t0 <= other[1] and other[2] <= t1
+            assert disjoint or inside or outside, (name, other[0])
+    chunks = {e[3]["chunk"] for e in serving if e[0] == "pim.launch"}
+    assert chunks == set(range(rec.n_chunks))
+
+
+def test_host_self_and_device_wait_fit_in_the_service_time(bank_grid, rng):
+    from repro import pim
+
+    s = pim.PimSession(grid=bank_grid, trace=False)
+    for workload in ("GEMV", "VA", "HST"):
+        entry = pim.registry()[workload]
+        s.map(workload, [entry.make_args(rng, 1) for _ in range(3)])
+    s.close()
+    recs = list(s.telemetry.records)
+    assert len(recs) == 9
+    for rec in recs:
+        assert rec.host_self_s > 0 and rec.device_wait_s >= 0
+        assert rec.host_self_s + rec.device_wait_s <= rec.service_s
+        assert rec.device_wait_s <= rec.phases.dpu_cpu
+
+
+def test_trace_view_summarises_a_session_export(bank_grid, rng, tmp_path):
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    import trace_view
+
+    from repro import pim
+
+    s = pim.PimSession(grid=bank_grid, trace=True)
+    entry = pim.registry()["GEMV"]
+    args = entry.make_args(rng, 1)
+    s.run("GEMV", *args)
+    s.run("GEMV", *args)                    # warm: served from residency
+    path = s.trace_export(tmp_path / "gemv.json")
+    s.close()
+    spans, _ = trace_view.split_events(trace_view.load_events(path))
+    assert trace_view.residency_summary(spans)["cached_spans"] > 0
+    stages = trace_view.stage_summary(spans)["stages"]
+    assert {"split", "cpu_dpu", "dpu", "dpu_cpu", "inter_dpu", "sched",
+            "queue", "session"} <= set(stages)
+    assert "bottleneck stage" in trace_view.render(path)

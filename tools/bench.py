@@ -238,7 +238,7 @@ def _observability_section(grid, names, smoke: bool) -> dict:
     n_probe = 10000
     t0 = time.perf_counter()
     for i in range(n_probe):
-        probe.emit("compute", "dpu", 0.0, 1.0, workload=wl, req=0, chunk=i)
+        probe.emit("launch", "dpu", 0.0, 1.0, workload=wl, req=0, chunk=i)
     emit_us = (time.perf_counter() - t0) / n_probe * 1e6
     return {
         "workload": wl,
@@ -423,7 +423,7 @@ def _decode_section(grid, smoke: bool) -> dict:
     moves only activations.  Each leg is a fresh traced session over the
     shared grid, best-of-reps on tokens/sec, with the weight bytes that
     crossed the boundary summed from the leg's ``scatter`` /
-    ``scatter:cached`` spans.  Both legs' tokens are checked against the
+    ``scatter_cached`` spans.  Both legs' tokens are checked against the
     pure-JAX ``greedy_generate`` so the timing can never come from a wrong
     answer — ``check_bench.py`` gates warm scatter ~ 0 and warm tokens/sec
     >= cold."""
@@ -483,7 +483,7 @@ def _decode_section(grid, smoke: bool) -> dict:
             "scatter_bytes": sum(s.args.get("bytes", 0) for s in spans
                                  if s.name == "scatter"),
             "cached_bytes": sum(s.args.get("bytes", 0) for s in spans
-                                if s.name == "scatter:cached"),
+                                if s.name == "scatter_cached"),
         }
 
     cold = leg(resident=False)

@@ -14,7 +14,7 @@ publishes into the job summary and what a quick local look needs:
   the gap below 1.0 is pipeline bubble — the quantity the paper's stacked
   bars can only show in aggregate (DESIGN.md §11);
 * **cached-scatter savings** — warm chunks served from the resident-operand
-  cache emit ``scatter:cached`` spans (DESIGN.md §12) instead of pushing
+  cache emit ``scatter_cached`` spans (DESIGN.md §12) instead of pushing
   bytes; the summary counts them, sums the bytes the elided pushes would
   have moved, and estimates the seconds saved from the mean duration of the
   cold ``scatter`` spans in the same trace.
@@ -96,7 +96,7 @@ def residency_summary(spans) -> dict:
     and an estimate of the seconds saved — cached count × the mean duration
     of the *cold* ``scatter`` spans in the same trace (the work a warm hit
     replaces)."""
-    cached = [e for e in spans if e["name"] == "scatter:cached"]
+    cached = [e for e in spans if e["name"] == "scatter_cached"]
     cold = [e for e in spans if e["name"] == "scatter"]
     cold_mean_s = (sum(e.get("dur", 0.0) for e in cold) / len(cold) / 1e6
                    if cold else 0.0)
