@@ -12,6 +12,7 @@ Both merge per-bank histograms on the host (tiny inter-DPU phase).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -64,13 +65,31 @@ def pim_long(grid: BankGrid, pixels: np.ndarray, nbins: int = 256):
 # that retrieve sums bank-wise and merge sums chunk-wise.  Both padding kinds
 # (split_chunks zeros at the chunk tail, pad_chunks -1 sentinels at the bank
 # tail) land in bin 0, so merge subtracts one precomputed spurious count.
-# Uses the HST-L scatter-add form per bank (exact, variant-independent math).
+# The chunked phase counts with a dense one-hot contraction (MXU and VPU, no
+# scatter); only pim_long keeps the serialized scatter-add, as HST-L's mutex
+# model (DESIGN.md §2).
+
+def _count(v, nbins: int):
+    """Histogram of ``v`` (1-D int32, clipped to ``[0, nbins)``) as a
+    two-factor one-hot contraction: bin ``b = hi * lo_n + lo`` with ``lo_n =
+    ceil(sqrt(nbins))`` and ``hi_n = ceil(nbins / lo_n)``, so ``onehot(hi)^T
+    @ onehot(lo)`` counts every (hi, lo) pair in ``hi_n + lo_n`` compares a
+    value, where a scatter-add makes one serialized update.  int8 operands,
+    int32 accumulation: exact while a bin holds fewer than 2**31 values."""
+    lo_n = math.isqrt(nbins - 1) + 1
+    hi_n = -(-nbins // lo_n)
+    v = jnp.clip(v, 0, nbins - 1)[:, None]
+    hi = (v // lo_n == jnp.arange(hi_n)).astype(jnp.int8)     # (n, hi_n)
+    lo = (v % lo_n == jnp.arange(lo_n)).astype(jnp.int8)      # (n, lo_n)
+    counts = jax.lax.dot_general(hi, lo, (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.int32)
+    return counts.reshape(hi_n * lo_n)[:nbins]
+
 
 @functools.cache
 def _local(grid: BankGrid, nbins: int):
     def local(pb):
-        clipped = jnp.clip(pb[0], 0, nbins - 1)
-        return jnp.zeros(nbins, jnp.int32).at[clipped].add(1)[None]
+        return _count(pb[0], nbins)[None]
     return jax.jit(grid.bank_local(local))
 
 

@@ -1,6 +1,7 @@
 """Compiles for a described TPU v5e chip: the Pallas kernels that serialized
-``pim()`` reaches, at the shapes ``kernels/ops.py`` pads to, and the chunked
-GEMV-B / GEMV-G compute phases at TinyLlama 1.1B widths.
+``pim()`` reaches, at the shapes ``kernels/ops.py`` pads to, the chunked
+GEMV-B / GEMV-G compute phases at TinyLlama 1.1B widths, and the chunked HST
+phase at the benchmark's chunk size.
 
 Nothing runs: the TPU compiler that ships with JAX compiles for a chip that
 is described, not attached, and raises what the chip's compiler would raise
@@ -9,6 +10,7 @@ that does not fit).  The topology is described inside a fixture, so only
 the process that runs these tests loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +24,7 @@ from repro.kernels import gemv as kgemv
 from repro.kernels import histogram as khist
 from repro.kernels import reduce as kred
 from repro.kernels import scan as kscan
-from repro.prim import gemv_fused
+from repro.prim import gemv_fused, hist
 
 #: a VA/RED/SCAN/HST-sized operand of the chip smoke run (scale 256), and
 #: the smallest padded length
@@ -103,6 +105,17 @@ def test_gemv_kernel_compiles(one_chip, m, n):
     _kernel_hlo(lambda a, x: kgemv.gemv(a, x, block_m=128, block_n=bn),
                 _spec((m, n_pad), jnp.float32, one_chip),
                 _spec((n_pad,), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("nbins", [256, 257])
+def test_hst_phase_compiles(bank_grid, nbins):
+    """One chunk of a 1536 x 1024 image (of four) on one bank: the counts
+    are an int8 contraction (a convolution on the chip), with no scatter."""
+    fn = hist._local(bank_grid, nbins)
+    text = fn.lower(_spec((1, 393216), jnp.int32,
+                          bank_grid.sharding(P(AXIS)))).compile().as_text()
+    assert re.search(r"\bconvolution\(", text)
+    assert not re.search(r"\bscatter\(", text)
 
 
 #: TinyLlama 1.1B projections as (d_out, d_in) row-major GEMV operands

@@ -1,5 +1,11 @@
 """PrIM suite: every workload's banked implementation vs its gold ref
-(single-bank here; 8-bank agreement in test_multibank.py)."""
+(single-bank here, but for chunked HST also at 4 banks; 8-bank agreement
+in test_multibank.py)."""
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -92,6 +98,74 @@ def test_hist(bank_grid, rng, variant):
     f = prim.hist.pim_short if variant == "short" else prim.hist.pim_long
     out, _ = f(bank_grid, px)
     assert (out == prim.hist.ref(px, 256)).all()
+
+
+# Chunked HST (the pipelined runtime's phases) against ref: values below 0
+# and at or above nbins, a length that divides by neither chunks nor banks,
+# so both the zero chunk-tail padding and (at 4 banks) the -1 bank padding
+# land in bin 0.  Four banks need four devices, fixed at jax init: one
+# subprocess computes every 4-bank case for the module.
+HIST_N, HIST_CHUNKS = 5003, 4
+HIST_BINS = [256, 100, 257]
+
+HIST_SCRIPT = r"""
+import sys; sys.path.insert(0, {src!r})
+import json
+from repro.core import make_bank_grid
+sys.path.insert(0, {tests!r})
+from test_prim import HIST_BINS, hist_chunked_case
+g = make_bank_grid()
+assert g.n_banks == 4, g.n_banks
+print(json.dumps({{nb: hist_chunked_case(g, nb) for nb in HIST_BINS}}))
+"""
+
+
+def hist_chunked_case(grid, nbins):
+    """Drive ``hist.chunked`` split -> scatter -> compute -> retrieve ->
+    merge on a seeded image; returns (result, ref) as lists."""
+    h = prim.hist.chunked
+    px = np.random.default_rng(nbins).integers(
+        -20, nbins + 20, HIST_N).astype(np.int32)
+    meta, chunks = h.split(grid, HIST_CHUNKS, px, nbins)
+    parts = [h.retrieve(grid, meta,
+                        h.compute(grid, meta, h.scatter(grid, meta, c)))
+             for c in chunks]
+    out = h.merge(grid, meta, parts)
+    return out.tolist(), prim.hist.ref(px, nbins).tolist()
+
+
+@pytest.fixture(scope="module")
+def hist_on_4_banks():
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    script = HIST_SCRIPT.format(src=os.path.join(here, "..", "src"),
+                                tests=here)
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return {int(k): v for k, v in json.loads(out.stdout.splitlines()[-1])
+            .items()}
+
+
+@pytest.mark.parametrize("banks", [1, 4])
+@pytest.mark.parametrize("nbins", HIST_BINS)
+def test_hist_chunked_exact(request, bank_grid, banks, nbins):
+    if banks == 1:
+        out, ref = hist_chunked_case(bank_grid, nbins)
+    else:
+        out, ref = request.getfixturevalue("hist_on_4_banks")[nbins]
+    assert len(out) == nbins
+    assert out == ref
+
+
+def test_hist_chunked_phase_has_no_scatter(bank_grid):
+    """The chunked compute phase counts with a dense contraction; a return
+    to the serialized scatter-add would put a ``stablehlo.scatter`` back."""
+    dp = bank_grid.to_banks(np.zeros((bank_grid.n_banks, 1024), np.int32))
+    text = prim.hist._local(bank_grid, 256).lower(dp).as_text()
+    assert "stablehlo.dot_general" in text
+    assert "stablehlo.scatter" not in text
 
 
 @pytest.mark.parametrize("via", ["host", "fabric"])
