@@ -11,6 +11,7 @@ FULL = ModelConfig(
     d_ff=1408, vocab=102400,
     moe_experts=64, moe_top_k=6, moe_shared_experts=2,
     moe_first_dense=True, dense_ff=10944,
+    moe_norm_topk=False,               # published norm_topk_prob: false
 )
 
 SMOKE = ModelConfig(
@@ -19,5 +20,5 @@ SMOKE = ModelConfig(
     d_ff=48, vocab=128,
     moe_experts=8, moe_top_k=2, moe_shared_experts=2,
     moe_first_dense=True, dense_ff=128, moe_capacity_factor=8.0,
-    dtype=jnp.float32, remat=False,
+    moe_norm_topk=False, dtype=jnp.float32, remat=False,
 )

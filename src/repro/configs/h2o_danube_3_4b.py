@@ -9,11 +9,12 @@ FULL = ModelConfig(
     n_layers=24, d_model=3840, n_heads=32, n_kv_heads=8,
     d_ff=10240, vocab=32000,
     window=4096,                       # Mistral-style SWA
+    norm_eps=1e-5,                     # published rms_norm_eps
 )
 
 SMOKE = ModelConfig(
     name="danube-smoke", family="dense",
     n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
-    d_ff=128, vocab=128, window=16,
+    d_ff=128, vocab=128, window=16, norm_eps=1e-5,
     dtype=jnp.float32, remat=False,
 )

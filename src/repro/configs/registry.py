@@ -21,7 +21,10 @@ ARCHS = [
     "musicgen_medium", "xlstm_125m", "deepseek_moe_16b", "kimi_k2_1t_a32b",
 ]
 
-ARCH_IDS = {a.replace("_", "-"): a for a in ARCHS}
+#: served by the decode engine, outside the dry-run grid above
+SERVED = ["deepseek_v2_lite"]
+
+ARCH_IDS = {a.replace("_", "-"): a for a in ARCHS + SERVED}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,7 +45,7 @@ SHAPES = {
 
 def get_config(arch: str, smoke: bool = False) -> ModelConfig:
     norm = arch.replace(".", "_").replace("-", "_")
-    if norm not in ARCHS:
+    if norm not in ARCHS + SERVED:
         raise KeyError(f"unknown arch {arch!r}; known: {sorted(ARCH_IDS)}")
     mod = importlib.import_module(f"repro.configs.{norm}")
     return mod.SMOKE if smoke else mod.FULL

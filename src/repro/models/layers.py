@@ -48,7 +48,8 @@ class ModelConfig:
     moe_shared_experts: int = 0
     moe_every: int = 1          # MoE layer every k-th layer
     moe_first_dense: bool = False
-    moe_capacity_factor: float = 1.25
+    moe_capacity_factor: float = 1.25   # 0 ⇒ dropless (capacity = tokens)
+    moe_norm_topk: bool = True  # renormalise the top-k gates to sum to 1
     dense_ff: int = 0           # d_ff of the non-MoE layers (jamba) / dense l0
     # hybrid (jamba)
     attn_every: int = 0         # 1 attention layer per this many (0 = all)
@@ -73,6 +74,20 @@ class ModelConfig:
     moe_ep: bool = False        # shard_map expert-parallel MoE (§Perf)
     scan_layers: bool = True    # lax.scan over the repeating group (False ⇒
     rope_theta: float = 1e4     # unrolled Python loop — exact cost_analysis)
+    norm_eps: float = 1e-6      # rms_norm epsilon of every block norm
+    # multi-head latent attention (DeepSeek-V2, models/mla.py); a
+    # kv_lora_rank > 0 replaces GQA attention with MLA in every layer
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (factor 0 ⇒ plain rope)
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     @property
     def hd(self) -> int:
@@ -89,6 +104,12 @@ class ModelConfig:
 def _param_count(cfg: ModelConfig, active_only: bool) -> float:
     d, hd = cfg.d_model, cfg.hd
     attn = d * hd * (cfg.n_heads + 2 * cfg.n_kv_heads) + cfg.n_heads * hd * d
+    if cfg.kv_lora_rank:
+        r, H = cfg.kv_lora_rank, cfg.n_heads
+        attn = (d * H * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+                + d * (r + cfg.qk_rope_head_dim)
+                + r * H * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+                + H * cfg.v_head_dim * d)
     total = 2.0 * cfg.vocab * d          # embed + head
     for li in range(cfg.n_layers):
         is_attn = cfg.attn_every == 0 or li % cfg.attn_every == 0

@@ -39,9 +39,19 @@ def init(key, cfg: ModelConfig):
 
 
 def _capacity(cfg: ModelConfig, n_tokens: int) -> int:
+    if cfg.moe_capacity_factor <= 0:        # dropless: one expert may
+        return max(8, -(-n_tokens // 8) * 8)  # take every token
     c = int(cfg.moe_capacity_factor * n_tokens * cfg.moe_top_k
             / cfg.moe_experts)
     return max(8, -(-c // 8) * 8)
+
+
+def _norm_gates(cfg: ModelConfig, gate):
+    """The top-k softmax gates, renormalised to sum to 1 where the model
+    says so (``moe_norm_topk``; DeepSeek-V2 and DeepSeekMoE do not)."""
+    if not cfg.moe_norm_topk:
+        return gate
+    return gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)
 
 
 def apply(p, cfg: ModelConfig, x, *, use_kernel: bool = False):
@@ -55,7 +65,7 @@ def apply(p, cfg: ModelConfig, x, *, use_kernel: bool = False):
     logits = (xt.astype(jnp.float32) @ p["router"])          # (T, E)
     probs = jax.nn.softmax(logits, axis=-1)
     gate, topk = jax.lax.top_k(probs, K)                     # (T, K)
-    gate = (gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)).astype(x.dtype)
+    gate = _norm_gates(cfg, gate).astype(x.dtype)
 
     # sort-based rank-in-expert
     ef = topk.reshape(-1)                                    # (T*K,)
@@ -156,8 +166,7 @@ def apply_ep(p, cfg: ModelConfig, x, *, model_axis: str = "model"):
         logits = (xt.astype(jnp.float32) @ router)       # (T_loc, E)
         probs = jax.nn.softmax(logits, axis=-1)
         gate, topk = jax.lax.top_k(probs, K)
-        gate = (gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)) \
-            .astype(xt.dtype)
+        gate = _norm_gates(cfg, gate).astype(xt.dtype)
         ef = topk.reshape(-1)
         order = jnp.argsort(ef)
         sorted_e = ef[order]

@@ -1,12 +1,13 @@
 """Model → PIM bridge: extract a decoder's per-layer matvec operands in the
 banked layout the decode engine pins on the ranks (DESIGN.md §14).
 
-The decode hot path is GEMV-dominant: per token, every layer runs four
-attention projections (q/k/v/o) and the two MLP halves (fused gate|up and
-down).  ``repro.pim.decode`` routes exactly those six matvecs through the
+The decode hot path is GEMV-dominant: per token, every layer runs its
+attention projections (q/k/v/o, or MLA's q/kv_a/o) and the two halves of
+each SwiGLU it uses (fused gate|up and down): the dense one, or the shared
+experts and the routed experts the router chose.  ``repro.pim.decode`` routes exactly those six matvecs through the
 PrIM workloads ``GEMV-B`` (``W @ x + b``) and ``GEMV-G`` (the SwiGLU gated
 hidden) — everything else (norms, rope, KV append, attention softmax,
-lm_head) stays on the host, where the model's own jnp functions keep the
+routing, lm_head) stays on the host, where the model's own jnp functions keep the
 numerics identical to :func:`repro.launch.serve.greedy_generate`.
 
 This module is the translation layer: it walks the transformer param tree
@@ -42,32 +43,45 @@ from .transformer import layer_plan
 
 @dataclasses.dataclass(frozen=True)
 class LayerWeights:
-    """One decoder layer's PIM-side operands + host-side norm scales.
+    """One decoder layer's PIM-side operands + host-side arrays.
 
-    ``q``/``k``/``v``/``o``/``down`` are GEMV-B pytrees ``{"w", "b"}``;
-    ``gate_up`` is the GEMV-G pytree ``{"wg", "wu"}``.  Each pytree is what
-    the engine wraps in one :class:`~repro.runtime.resident.ResidentHandle`
-    and pins as a unit.
+    ``mats`` maps each matvec of the layer to its operand pytree: GEMV-B
+    ``{"w", "b"}`` or, for a SwiGLU's gated half (a name ending in
+    ``up``), GEMV-G ``{"wg", "wu"}``.  Each pytree is what the engine wraps
+    in one :class:`~repro.runtime.resident.ResidentHandle` and pins as a
+    unit.  The names:
+
+    * attention: ``q``, ``k``, ``v``, ``o``; latent attention (MLA):
+      ``q``, ``kv_a`` (the compressed KV and the rope key), ``o``;
+    * dense SwiGLU: ``up``, ``down``; MoE: the shared experts fused into
+      one SwiGLU, ``shared.up`` / ``shared.down``, and each routed expert
+      ``e<i>.up`` / ``e<i>.down``.
+
+    ``host`` holds what stays on the host: the norm scales ``norm1``,
+    ``norm2``; for MLA ``kv_norm`` and ``wkv_b`` (absorbed into the query
+    and the output, ``models/mla.py``); for MoE the ``router`` (d, E).
     """
 
-    q: dict
-    k: dict
-    v: dict
-    o: dict
-    gate_up: dict
-    down: dict
-    norm1: Any                 # (d,) host-side rms_norm scales
-    norm2: Any
+    mats: dict
+    host: dict
+    n_experts: int = 0
+
+
+def workload_of(proj: str) -> str:
+    """The PrIM workload that serves matvec ``proj``."""
+    return "GEMV-G" if proj.endswith("up") else "GEMV-B"
 
 
 def validate_decode_config(cfg: ModelConfig) -> None:
     """Reject configs outside the decode engine's contract.
 
-    The engine replicates ``transformer.decode_step`` for the plain
-    attention + dense-SwiGLU block only; anything that changes the block
-    dataflow (parallel residual, MoE routing, SSM/xLSTM mixers, cross
-    attention) or the numerics contract (non-float32 params) raises here,
-    at construction, instead of silently diverging from the reference.
+    The engine replicates ``transformer.decode_step`` for two blocks: plain
+    attention + dense SwiGLU, and DeepSeek-V2's latent attention (MLA) +
+    dense SwiGLU or MoE (routed + shared experts).  Anything that changes
+    the block dataflow (parallel residual, SSM/xLSTM mixers, cross
+    attention, MoE under plain attention) or the numerics contract
+    (non-float32 params) raises here, at construction, instead of silently
+    diverging from the reference.
     """
     if cfg.dtype != jnp.float32:
         raise ValueError(
@@ -79,16 +93,19 @@ def validate_decode_config(cfg: ModelConfig) -> None:
             "the residual dataflow — not supported by the decode engine")
     pro, period, _ = layer_plan(cfg)
     for li, desc in enumerate(pro + period):
-        if desc["mixer"] != "attn":
+        if desc["mixer"] not in ("attn", "mla"):
             raise ValueError(
                 f"{cfg.name} layer {li}: mixer {desc['mixer']!r} is not "
-                "offloadable — the decode engine handles attention blocks "
-                "only (mamba/xlstm/cross layers have no GEMV hot path)")
-        if desc["ffn"] != "dense":
+                "offloadable — the decode engine handles attention and "
+                "latent-attention blocks only (mamba/xlstm/cross layers "
+                "have no GEMV hot path)")
+        if desc["ffn"] == "none" or (desc["ffn"] == "moe"
+                                     and desc["mixer"] != "mla"):
             raise ValueError(
-                f"{cfg.name} layer {li}: ffn {desc['ffn']!r} — only the "
-                "dense SwiGLU FFN maps onto GEMV-G/GEMV-B (MoE routing is "
-                "token-dependent; 'none' has nothing to offload)")
+                f"{cfg.name} layer {li}: ffn {desc['ffn']!r} — the dense "
+                "SwiGLU maps onto GEMV-G/GEMV-B, and MoE is served only in "
+                "DeepSeek-V2's latent-attention block ('none' has nothing "
+                "to offload)")
 
 
 def _f32(a) -> np.ndarray:
@@ -97,12 +114,27 @@ def _f32(a) -> np.ndarray:
 
 def _rows(a) -> np.ndarray:
     """Transpose to the row-sharded (d_out, d_in) GEMV layout, contiguous
-    so the per-chunk device pushes are single copies."""
+    so the per-chunk device pushes are single copies (no copy at all when
+    ``a`` is already the transpose of a row-major array)."""
     return np.ascontiguousarray(_f32(a).T)
 
 
 def _bias(p: dict, key: str, n: int) -> np.ndarray:
     return _f32(p[key]) if key in p else np.zeros(n, np.float32)
+
+
+def _gemv_b(w, b=None) -> dict:
+    w = _rows(w)
+    return {"w": w, "b": np.zeros(w.shape[0], np.float32) if b is None
+            else b}
+
+
+def _swiglu(ffn: dict, prefix: str) -> dict:
+    """``<prefix>up`` (GEMV-G over the fused gate|up) and ``<prefix>down``."""
+    wi = _f32(ffn["wi"])                       # (d, 2f) fused gate|up
+    f = wi.shape[1] // 2
+    return {prefix + "up": {"wg": _rows(wi[:, :f]), "wu": _rows(wi[:, f:])},
+            prefix + "down": _gemv_b(ffn["wo"])}
 
 
 def _layer_params(params, n_prologue: int, period_len: int, li: int):
@@ -115,27 +147,45 @@ def _layer_params(params, n_prologue: int, period_len: int, li: int):
     return jax.tree.map(lambda a: a[r], params["group"][pos])
 
 
+def per_layer_params(params, cfg: ModelConfig) -> list[dict]:
+    """Every global layer's param dict, in layer order (views of the
+    stacked group leaves where ``params`` holds numpy arrays)."""
+    pro, period, _ = layer_plan(cfg)
+    return [_layer_params(params, len(pro), max(len(period), 1), li)
+            for li in range(cfg.n_layers)]
+
+
 def extract_decode_weights(params, cfg: ModelConfig) -> list[LayerWeights]:
     """Per-global-layer PIM operands for every decoder layer, in layer
     order.  Validates the config first; the result is position-stable, so
     the engine's (layer, proj) handle map survives across steps."""
     validate_decode_config(cfg)
-    pro, period, _ = layer_plan(cfg)
-    d, hd = cfg.d_model, cfg.hd
-    H, KVH = cfg.n_heads, cfg.n_kv_heads
+    pro, period, repeats = layer_plan(cfg)
+    descs = pro + period * repeats
+    H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     layers = []
-    for li in range(cfg.n_layers):
-        p = _layer_params(params, len(pro), max(len(period), 1), li)
-        m = p["mixer"]
-        wi = _f32(p["ffn"]["wi"])                  # (d, 2f) fused gate|up
-        f = wi.shape[1] // 2
-        layers.append(LayerWeights(
-            q={"w": _rows(m["wq"]), "b": _bias(m, "bq", H * hd)},
-            k={"w": _rows(m["wk"]), "b": _bias(m, "bk", KVH * hd)},
-            v={"w": _rows(m["wv"]), "b": _bias(m, "bv", KVH * hd)},
-            o={"w": _rows(m["wo"]), "b": np.zeros(d, np.float32)},
-            gate_up={"wg": np.ascontiguousarray(wi[:, :f].T),
-                     "wu": np.ascontiguousarray(wi[:, f:].T)},
-            down={"w": _rows(p["ffn"]["wo"]), "b": np.zeros(d, np.float32)},
-            norm1=p["norm1"], norm2=p["norm2"]))
+    for li, p in enumerate(per_layer_params(params, cfg)):
+        m, ffn = p["mixer"], p["ffn"]
+        host = {"norm1": p["norm1"], "norm2": p["norm2"]}
+        if descs[li]["mixer"] == "mla":
+            mats = {"q": _gemv_b(m["wq"]), "kv_a": _gemv_b(m["wkv_a"]),
+                    "o": _gemv_b(m["wo"])}
+            host |= {"kv_norm": m["kv_norm"], "wkv_b": m["wkv_b"]}
+        else:
+            mats = {"q": _gemv_b(m["wq"], _bias(m, "bq", H * hd)),
+                    "k": _gemv_b(m["wk"], _bias(m, "bk", KVH * hd)),
+                    "v": _gemv_b(m["wv"], _bias(m, "bv", KVH * hd)),
+                    "o": _gemv_b(m["wo"])}
+        n_experts = 0
+        if descs[li]["ffn"] == "moe":
+            n_experts = cfg.moe_experts
+            mats |= _swiglu(ffn["shared"], "shared.")
+            for e in range(n_experts):
+                mats |= _swiglu({"wi": ffn["wi"][e], "wo": ffn["wo"][e]},
+                                f"e{e}.")
+            host["router"] = ffn["router"]
+        else:
+            mats |= _swiglu(ffn, "")
+        layers.append(LayerWeights(mats=mats, host=host,
+                                   n_experts=n_experts))
     return layers

@@ -6,7 +6,7 @@ cadences (attn_every / moe_every / cross_attn_every / slstm_every), so
 homogeneous stacks compile as a single ``lax.scan`` step (small HLO, fast
 multi-cell dry-runs) with optional per-group remat.
 
-Block kinds: attn | mamba | mlstm | slstm | cross;  FFN: dense | moe | none.
+Block kinds: attn | mla | mamba | mlstm | slstm | cross;  FFN: dense | moe | none.
 """
 from __future__ import annotations
 
@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from . import attention, mamba, moe, xlstm
+from . import attention, mamba, mla, moe, xlstm
 from .layers import ModelConfig, dense_init, emb_axis, mlp_init, rms_norm, swiglu
 
 
@@ -35,7 +35,7 @@ def _desc(cfg: ModelConfig, li: int) -> dict:
             li % cfg.cross_attn_every == cfg.cross_attn_every - 1:
         mixer = "cross"
     else:
-        mixer = "attn"
+        mixer = "mla" if cfg.kv_lora_rank else "attn"
     is_moe = (cfg.moe_experts > 0 and li % cfg.moe_every == 0
               and not (cfg.moe_first_dense and li == 0))
     if is_moe:
@@ -72,6 +72,8 @@ def _block_init(key, cfg: ModelConfig, desc: dict):
     mixer = desc["mixer"]
     if mixer in ("attn", "cross"):
         params["mixer"], specs["mixer"] = attention.init(km, cfg)
+    elif mixer == "mla":
+        params["mixer"], specs["mixer"] = mla.init(km, cfg)
     elif mixer == "mamba":
         params["mixer"], specs["mixer"] = mamba.init(km, cfg)
     elif mixer == "mlstm":
@@ -91,10 +93,12 @@ def _block_init(key, cfg: ModelConfig, desc: dict):
 
 def _block_apply(p, cfg: ModelConfig, desc: dict, x, frontend, use_kernel):
     aux = jnp.zeros((), jnp.float32)
-    h = rms_norm(x, p["norm1"])
+    h = rms_norm(x, p["norm1"], cfg.norm_eps)
     mixer = desc["mixer"]
     if mixer == "attn":
         mo = attention.apply(p["mixer"], cfg, h, use_kernel=use_kernel)
+    elif mixer == "mla":
+        mo = mla.apply(p["mixer"], cfg, h)
     elif mixer == "cross":
         mo = attention.apply_cross(p["mixer"], cfg, h, frontend)
     elif mixer == "mamba":
@@ -111,7 +115,7 @@ def _block_apply(p, cfg: ModelConfig, desc: dict, x, frontend, use_kernel):
         fo = swiglu(h, p["ffn"]["wi"], p["ffn"]["wo"])
         return x + mo + fo, aux
     x = x + mo
-    h2 = rms_norm(x, p["norm2"])
+    h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
     if desc["ffn"] == "moe":
         if cfg.moe_ep:
             fo, aux = moe.apply_ep(p["ffn"], cfg, h2)
@@ -189,7 +193,7 @@ def trunk(params, cfg: ModelConfig, tokens=None, embeds=None,
                 lp = jax.tree.map(lambda a: a[r], params["group"])
                 (x, aux), _ = body((x, aux), lp)
 
-    return rms_norm(x, params["final_norm"]), aux
+    return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
 
 
 def forward(params, cfg: ModelConfig, tokens=None, embeds=None,
@@ -267,6 +271,8 @@ def _block_cache(cfg: ModelConfig, desc: dict, batch: int, max_len: int,
     mixer = desc["mixer"]
     if mixer == "attn":
         return attention.init_cache(cfg, batch, max_len)
+    if mixer == "mla":
+        return mla.init_cache(cfg, batch, max_len)
     if mixer == "cross":
         # precomputed cross K/V from the frontend tokens
         B, T, _ = frontend.shape
@@ -308,9 +314,11 @@ def _stack_caches(cfg, period, batch, max_len, repeats, frontend):
 
 def _block_decode(p, cfg, desc, x, cache, frontend):
     mixer = desc["mixer"]
-    h = rms_norm(x, p["norm1"])
+    h = rms_norm(x, p["norm1"], cfg.norm_eps)
     if mixer == "attn":
         mo, cache = attention.decode(p["mixer"], cfg, h, cache)
+    elif mixer == "mla":
+        mo, cache = mla.decode(p["mixer"], cfg, h, cache)
     elif mixer == "cross":
         q = (h @ p["mixer"]["wq"]).reshape(
             x.shape[0], 1, cfg.n_heads, cfg.hd).transpose(0, 2, 1, 3)
@@ -333,7 +341,7 @@ def _block_decode(p, cfg, desc, x, cache, frontend):
         fo = swiglu(h, p["ffn"]["wi"], p["ffn"]["wo"])
         return x + mo + fo, cache
     x = x + mo
-    h2 = rms_norm(x, p["norm2"])
+    h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
     if desc["ffn"] == "moe":
         fo, _ = moe.apply(p["ffn"], cfg, h2)
     else:
@@ -379,6 +387,6 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, embeds=None,
             group_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
         new_cache["group"] = group_cache
 
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = x @ params["lm_head"]
     return logits, new_cache

@@ -34,10 +34,24 @@ so tokens match :func:`repro.launch.serve.greedy_generate` exactly):
     host: rms_norm ─ PIM: q,k,v ─ host: rope + KV append + attention
     ─ PIM: o ─ host: residual + rms_norm ─ PIM: gate|up ─ PIM: down
     ─ host: residual    (per layer; then final norm + lm_head + argmax)
+
+DeepSeek-V2's block (latent attention + MoE, DESIGN.md §14) splits the
+same way, with the routing on the host:
+
+    host: rms_norm ─ PIM: q, kv_a ─ host: MLA over the latent cache
+    (``wkv_b`` absorbed) ─ PIM: o ─ host: residual + rms_norm + router
+    (softmax, top-k, no renormalisation unless the model says so, no
+    capacity) ─ PIM: gate|up of the shared and the k chosen experts ─ PIM:
+    their downs ─ host: residual + shared + Σ gate·expert
+
+:meth:`DecodeEngine.prefill` builds the streams' latent caches from whole
+prompts in one block forward on the device, against the same pinned
+weights, so that a long context costs no per-token requests.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Sequence
 
@@ -46,20 +60,24 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops as kops
-from repro.models import attention
+from repro.models import attention, mla
 from repro.models.layers import ModelConfig, rms_norm, rope
-from repro.models.pim_bridge import LayerWeights, extract_decode_weights
+from repro.models.pim_bridge import (LayerWeights, extract_decode_weights,
+                                     workload_of)
 from repro.runtime.qos import RequestOptions
 from repro.runtime.resident import ResidentHandle
-from repro.runtime.trace import get_tracer
+from repro.runtime.trace import get_tracer, span
 
 from .session import PimSession, session as open_session
 
-#: projection label -> PrIM workload that serves it
+#: projection label -> PrIM workload that serves it (the attention block's
+#: names; every other matvec follows ``pim_bridge.workload_of``)
 PROJ_WORKLOADS = {"q": "GEMV-B", "k": "GEMV-B", "v": "GEMV-B",
                   "o": "GEMV-B", "up": "GEMV-G", "down": "GEMV-B"}
 
-#: engine-measured step phases: the four PIM groups + everything else
+#: engine-measured step phases: the PIM groups + everything else ("qkv"
+#: is MLA's q + kv_a; a model with MoE layers adds "experts", their shared
+#: and routed experts)
 PIM_GROUPS = ("qkv", "o", "up", "down")
 
 
@@ -68,27 +86,131 @@ class StepRecord:
     """Where one engine step's wall time went — measured by the engine
     around each submit→drain group and each host segment, independently of
     the telemetry rows the same step produces (the test battery checks the
-    two views agree)."""
+    two views agree).  ``route_s`` (routing), ``attend_s`` (the attention
+    host half) are parts of ``host_s``; ``experts_s`` is
+    ``pim_s["experts"]``, over ``expert_requests`` requests."""
 
     step: int
     tokens: int              # newly *generated* tokens (0 while prefilling)
     wall_s: float
-    pim_s: dict              # group ("qkv"|"o"|"up"|"down") -> seconds
+    pim_s: dict              # group (DecodeEngine.groups) -> seconds
     host_s: float
+    route_s: float = 0.0
+    attend_s: float = 0.0
+    experts_s: float = 0.0
+    expert_requests: int = 0
 
 
 class _Stream:
-    """One decode stream: its tenant name, emitted tokens, and per-layer
-    KV caches (host-side, exactly ``attention.init_cache``'s layout)."""
+    """One decode stream: its tenant name, its tokens (the last one is fed
+    next) and per-layer caches on the host device — ``attention.init_cache``'s
+    layout, or for MLA the latent ``c`` (T, r) and rope key ``pe``
+    (T, rope) with ``pos`` positions filled."""
 
-    __slots__ = ("name", "tokens", "caches")
+    __slots__ = ("name", "tokens", "caches", "pos")
 
     def __init__(self, name: str, cfg: ModelConfig, max_len: int,
-                 first_token: int):
+                 tokens: Sequence[int]):
         self.name = name
-        self.tokens = [int(first_token)]
-        self.caches = [attention.init_cache(cfg, 1, max_len, jnp.float32)
-                       for _ in range(cfg.n_layers)]
+        self.tokens = [int(t) for t in tokens]
+        self.pos = 0
+        if cfg.kv_lora_rank:
+            self.caches = [
+                {"c": jnp.zeros((max_len, cfg.kv_lora_rank), jnp.float32),
+                 "pe": jnp.zeros((max_len, cfg.qk_rope_head_dim),
+                                 jnp.float32)}
+                for _ in range(cfg.n_layers)]
+        else:
+            self.caches = [attention.init_cache(cfg, 1, max_len, jnp.float32)
+                           for _ in range(cfg.n_layers)]
+
+
+# -- host halves of the latent-attention / MoE block (jitted, fixed shapes) ----
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _mla_decode(cfg, q, kva, kv_norm, wkv_b, c, pe, pos):
+    """One position of one stream: latent + rope key into the cache at
+    ``pos``, absorbed attention over positions ``<= pos``."""
+    p = jnp.reshape(pos, (1, 1))
+    q_nope, q_pe, c_kv, k_pe = mla.latent(
+        cfg, q.reshape(1, 1, -1), kva.reshape(1, 1, -1), kv_norm, p)
+    c = jax.lax.dynamic_update_slice(c, c_kv[0], (pos, 0))
+    pe = jax.lax.dynamic_update_slice(pe, k_pe[0], (pos, 0))
+    o = mla.attend_latent(cfg, q_nope[:, :, 0], q_pe[:, :, 0], c[None],
+                          pe[None], p[0] + 1, wkv_b)
+    return o[0], c, pe
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _route(k, h, router):
+    """Softmax over the experts, top-k: (gates (B, k), experts (B, k))."""
+    probs = jax.nn.softmax(h.astype(jnp.float32) @ router, axis=-1)
+    return jax.lax.top_k(probs, k)
+
+
+@jax.jit
+def _combine(x, shared, ys, gates):
+    """x + shared + Σ_e gate_e · y_e for one stream."""
+    return x + (shared + gates @ ys).reshape(x.shape)
+
+
+# -- block prefill on the device, against the pinned row chunks ----------------
+
+def _mv_rows(x, chunks, m):
+    """x (S, d_in) against a GEMV-B operand's row chunks [(w (1, per, d_in),
+    b (1, per))]: (S, m)."""
+    y = jnp.concatenate([x @ w[0].T + b[0] for w, b in chunks], -1)
+    return y[:, :m]
+
+
+def _swiglu_rows(x, up, down, m_up, m_down):
+    g = jnp.concatenate([x @ wg[0].T for wg, _ in up], -1)[:, :m_up]
+    u = jnp.concatenate([x @ wu[0].T for _, wu in up], -1)[:, :m_up]
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+    return _mv_rows(h, down, m_down)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _prefill_attn(cfg, ms, x, w, cache):
+    """x (P, d) + MLA over the block; the block's latents into the cache's
+    first P rows."""
+    P = x.shape[0]
+    h = rms_norm(x, w["norm1"], cfg.norm_eps)
+    q = _mv_rows(h, w["q"], ms[0])
+    kva = _mv_rows(h, w["kv_a"], ms[1])
+    q_nope, q_pe, c_kv, k_pe = mla.latent(
+        cfg, q[None], kva[None], w["kv_norm"], jnp.arange(P)[None])
+    o = mla.attend_block(cfg, q_nope, q_pe, c_kv, k_pe, w["wkv_b"])[0]
+    cache = {"c": jax.lax.dynamic_update_slice(cache["c"], c_kv[0], (0, 0)),
+             "pe": jax.lax.dynamic_update_slice(cache["pe"], k_pe[0],
+                                                (0, 0))}
+    return x + _mv_rows(o, w["o"], ms[2]), cache
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _prefill_dense(cfg, ms, x, w):
+    h = rms_norm(x, w["norm2"], cfg.norm_eps)
+    return x + _swiglu_rows(h, w["up"], w["down"], *ms)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _prefill_moe(cfg, ms, x, w):
+    """Shared experts added, and the routing of every position."""
+    h = rms_norm(x, w["norm2"], cfg.norm_eps)
+    gates, idx = _route(cfg.moe_top_k, h, w["router"])
+    return x + _swiglu_rows(h, w["up"], w["down"], *ms), h, gates, idx
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _prefill_expert(ms, x, h, rows, gates, up, down):
+    """One routed expert over the positions ``rows`` (padded with gate 0)."""
+    y = _swiglu_rows(h[rows], up, down, *ms)
+    return x.at[rows].add(gates[:, None] * y)
+
+
+def _bucket(n: int, least: int = 16) -> int:
+    """The power of two >= n (and >= least): one compile per bucket."""
+    return max(least, 1 << max(0, n - 1).bit_length())
 
 
 class DecodeEngine:
@@ -108,8 +230,9 @@ class DecodeEngine:
                  n_chunks: int = 2, resident: bool = True, pin: bool = True,
                  step_deadline_s: float | None = None):
         self.cfg = cfg
-        self.params = params
         self.layers: list[LayerWeights] = extract_decode_weights(params, cfg)
+        self.groups = PIM_GROUPS + (
+            ("experts",) if any(lw.n_experts for lw in self.layers) else ())
         self._own = session is None
         if session is None:
             session = open_session(banks=banks, ranks=ranks,
@@ -117,56 +240,70 @@ class DecodeEngine:
         self.session = session
         self.step_deadline_s = step_deadline_s
         self.steps: list[StepRecord] = []
+        self.streams: list[_Stream] = []
+        #: logits (B, V) of the last step, on the host device
+        self.last_logits = None
         # one handle per (layer, proj): the digest is computed once here;
         # every submit and the pin below reuse it (no per-step rehash)
-        self.handles: dict[tuple[int, str], ResidentHandle] = {}
-        for li, lw in enumerate(self.layers):
-            for proj in PROJ_WORKLOADS:
-                attr = "gate_up" if proj == "up" else proj
-                self.handles[(li, proj)] = ResidentHandle(getattr(lw, attr))
+        self.handles: dict[tuple[int, str], ResidentHandle] = {
+            (li, proj): ResidentHandle(op)
+            for li, lw in enumerate(self.layers)
+            for proj, op in lw.mats.items()}
         self.pins: list[str] = []
+        self.pinned: dict[tuple[int, str], str] = {}
         self.setup_s = 0.0
         if pin and session.cache is not None:
             t0 = time.perf_counter()
-            for (li, proj), handle in self.handles.items():
-                x = np.zeros(self._in_dim(li, proj), np.float32)
-                self.pins.append(
-                    session.pin(PROJ_WORKLOADS[proj], handle, x))
+            for key, handle in self.handles.items():
+                x = np.zeros(self._in_dim(key), np.float32)
+                fp = session.pin(workload_of(key[1]), handle, x)
+                self.pins.append(fp)
+                self.pinned[key] = fp
             self.setup_s = time.perf_counter() - t0
+        # the host half's weights, on the host device
+        self.embed = jnp.asarray(params["embed"])
+        self.final_norm = jnp.asarray(params["final_norm"])
+        self.lm_head = jnp.asarray(params["lm_head"])
+        self.host = [{k: jnp.asarray(v) for k, v in lw.host.items()}
+                     for lw in self.layers]
 
-    def _in_dim(self, li: int, proj: str) -> int:
-        lw = self.layers[li]
-        if proj == "o":
-            return lw.o["w"].shape[1]          # H * hd
-        if proj == "down":
-            return lw.down["w"].shape[1]       # d_ff
-        return self.cfg.d_model
+    def _in_dim(self, key) -> int:
+        op = self.handles[key].value
+        return (op["w"] if "w" in op else op["wg"]).shape[1]
 
-    # -- one projection group across all streams -------------------------------
+    def _rows(self, li: int, proj: str) -> int:
+        op = self.handles[(li, proj)].value
+        return (op["w"] if "w" in op else op["wg"]).shape[0]
 
-    def _group(self, li: int, projs: Sequence[str],
-               vecs_per_stream: Sequence[Sequence[np.ndarray]],
-               streams: Sequence[_Stream]) -> tuple[list, float]:
-        """Submit ``projs`` (e.g. ``("q","k","v")``) for every stream, run
-        the group to completion, and return (results stream-major in proj
-        order, group wall seconds).  Same-tenant consecutive submissions of
-        one workload coalesce into one chunk-pipeline batch."""
+    # -- one group of matvecs across all streams -------------------------------
+
+    def _group(self, li: int, items: Sequence[Sequence[tuple[str, object]]],
+               streams: Sequence[_Stream]) -> tuple[list, float, list]:
+        """Submit ``items[b]`` — (proj, vector) pairs — for every stream
+        ``b``, run the group to completion, and return (results per stream
+        in item order, group wall seconds, the requests).  Same-tenant
+        consecutive submissions of one workload coalesce into one
+        chunk-pipeline batch."""
         t0 = time.perf_counter()
         reqs = []
-        for s, vecs in zip(streams, vecs_per_stream):
-            for proj, vec in zip(projs, vecs):
+        for s, its in zip(streams, items):
+            for proj, vec in its:
                 opts = RequestOptions(tenant=s.name,
                                       deadline_s=self.step_deadline_s,
                                       tags={"layer": li, "proj": proj})
                 reqs.append(self.session.submit(
-                    PROJ_WORKLOADS[proj], self.handles[(li, proj)],
+                    workload_of(proj), self.handles[(li, proj)],
                     np.asarray(vec, np.float32), options=opts))
         if not self.session.serving:
             self.session.drain()
-        results = [r.result() for r in reqs]
-        return results, time.perf_counter() - t0
+        flat = [r.result() for r in reqs]
+        out, i = [], 0
+        for its in items:
+            out.append(flat[i:i + len(its)])
+            i += len(its)
+        return out, time.perf_counter() - t0, reqs
 
-    # -- one step: every stream advances one token -----------------------------
+    # -- attention host halves --------------------------------------------------
 
     def _attend(self, stream: _Stream, li: int, qv, kv, vv) -> np.ndarray:
         """Host half of the attention block for one stream: rope, KV append
@@ -193,6 +330,16 @@ class DecodeEngine:
         stream.caches[li] = {"k": kc, "v": vc, "len": lengths}
         return np.asarray(o.transpose(0, 2, 1, 3).reshape(-1), np.float32)
 
+    def _attend_mla(self, stream: _Stream, li: int, qv, kvav) -> np.ndarray:
+        """Host half of latent attention for one stream at its position."""
+        w, cache = self.host[li], stream.caches[li]
+        o, cache["c"], cache["pe"] = _mla_decode(
+            self.cfg, jnp.asarray(qv), jnp.asarray(kvav), w["kv_norm"],
+            w["wkv_b"], cache["c"], cache["pe"], stream.pos)
+        return np.asarray(o, np.float32)
+
+    # -- one step: every stream advances one token -----------------------------
+
     def _step(self, streams: Sequence[_Stream], toks: np.ndarray,
               step: int, generated: bool) -> np.ndarray:
         """Advance every stream one position on input tokens ``toks``
@@ -200,68 +347,122 @@ class DecodeEngine:
         appends this step's :class:`StepRecord`."""
         cfg = self.cfg
         d = cfg.d_model
+        eps = cfg.norm_eps
         t0 = time.perf_counter()
-        host_s = 0.0
-        pim_s = dict.fromkeys(PIM_GROUPS, 0.0)
+        rec = StepRecord(step=step, tokens=len(streams) if generated else 0,
+                         wall_s=0.0, pim_s=dict.fromkeys(self.groups, 0.0),
+                         host_s=0.0)
+        pim_s = rec.pim_s
 
         th = time.perf_counter()
-        xs = [self.params["embed"][jnp.asarray(t).reshape(1, 1)]
+        xs = [self.embed[jnp.asarray(t).reshape(1, 1)]
               for t in toks]                                # (1, 1, d) each
-        host_s += time.perf_counter() - th
+        rec.host_s += time.perf_counter() - th
 
         for li, lw in enumerate(self.layers):
+            w = self.host[li]
             th = time.perf_counter()
-            hv = [np.asarray(rms_norm(x, lw.norm1)).reshape(-1) for x in xs]
-            host_s += time.perf_counter() - th
+            hv = [np.asarray(rms_norm(x, w["norm1"], eps)).reshape(-1)
+                  for x in xs]
+            rec.host_s += time.perf_counter() - th
 
-            qkv, dt = self._group(li, ("q", "k", "v"),
-                                  [(h, h, h) for h in hv], streams)
-            pim_s["qkv"] += dt
+            if cfg.kv_lora_rank:
+                qkv, dt, _ = self._group(li, [[("q", h), ("kv_a", h)]
+                                              for h in hv], streams)
+                pim_s["qkv"] += dt
+                th = time.perf_counter()
+                with span("mla", "session", layer=li):
+                    ov = [self._attend_mla(s, li, *r)
+                          for s, r in zip(streams, qkv)]
+                dt = time.perf_counter() - th
+                rec.attend_s += dt
+            else:
+                qkv, dt, _ = self._group(li, [[("q", h), ("k", h), ("v", h)]
+                                              for h in hv], streams)
+                pim_s["qkv"] += dt
+                th = time.perf_counter()
+                ov = [self._attend(s, li, *r) for s, r in zip(streams, qkv)]
+                dt = time.perf_counter() - th
+            rec.host_s += dt
 
-            th = time.perf_counter()
-            ov = [self._attend(s, li, *qkv[3 * b:3 * b + 3])
-                  for b, s in enumerate(streams)]
-            host_s += time.perf_counter() - th
-
-            mo, dt = self._group(li, ("o",), [(o,) for o in ov], streams)
+            mo, dt, _ = self._group(li, [[("o", o)] for o in ov], streams)
             pim_s["o"] += dt
 
             th = time.perf_counter()
-            xs = [x + jnp.asarray(m).reshape(1, 1, d)
+            xs = [x + jnp.asarray(m[0]).reshape(1, 1, d)
                   for x, m in zip(xs, mo)]
-            h2 = [np.asarray(rms_norm(x, lw.norm2)).reshape(-1) for x in xs]
-            host_s += time.perf_counter() - th
+            h2 = [np.asarray(rms_norm(x, w["norm2"], eps)).reshape(-1)
+                  for x in xs]
+            rec.host_s += time.perf_counter() - th
 
-            hidden, dt = self._group(li, ("up",), [(h,) for h in h2],
-                                     streams)
+            if lw.n_experts:
+                xs = self._moe(li, xs, h2, streams, rec)
+                continue
+            hidden, dt, _ = self._group(li, [[("up", h)] for h in h2],
+                                        streams)
             pim_s["up"] += dt
-            down, dt = self._group(li, ("down",), [(h,) for h in hidden],
-                                   streams)
+            down, dt, _ = self._group(li, [[("down", h[0])]
+                                           for h in hidden], streams)
             pim_s["down"] += dt
 
             th = time.perf_counter()
-            xs = [x + jnp.asarray(dn).reshape(1, 1, d)
+            xs = [x + jnp.asarray(dn[0]).reshape(1, 1, d)
                   for x, dn in zip(xs, down)]
-            host_s += time.perf_counter() - th
+            rec.host_s += time.perf_counter() - th
 
         th = time.perf_counter()
-        nxt = []
-        for x in xs:
-            h = rms_norm(x, self.params["final_norm"])
-            logits = h @ self.params["lm_head"]             # (1, 1, V)
-            nxt.append(int(jnp.argmax(logits[:, -1, :], axis=-1)[0]))
-        host_s += time.perf_counter() - th
+        h = rms_norm(jnp.concatenate(xs, 0), self.final_norm, eps)
+        logits = (h @ self.lm_head)[:, -1, :]               # (B, V)
+        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+        self.last_logits = logits
+        rec.host_s += time.perf_counter() - th
 
-        wall = time.perf_counter() - t0
-        self.steps.append(StepRecord(
-            step=step, tokens=len(streams) if generated else 0,
-            wall_s=wall, pim_s=pim_s, host_s=host_s))
+        rec.wall_s = time.perf_counter() - t0
+        rec.experts_s = pim_s.get("experts", 0.0)
+        self.steps.append(rec)
         tr = get_tracer()
         if tr.enabled:
-            tr.emit("decode_step", "session", t0, t0 + wall, track="decode",
-                    step=step, streams=len(streams),
+            tr.emit("decode_step", "session", t0, t0 + rec.wall_s,
+                    track="decode", step=step, streams=len(streams),
                     generated=int(generated))
-        return np.asarray(nxt, np.int32)
+        return nxt
+
+    def _moe(self, li: int, xs, h2, streams, rec: StepRecord) -> list:
+        """Route on the host, then the shared and the chosen experts of
+        every stream as one group: gate|up requests, then downs."""
+        cfg, w = self.cfg, self.host[li]
+        th = time.perf_counter()
+        with span("route", "session", layer=li):
+            gates, idx = _route(cfg.moe_top_k, jnp.asarray(np.stack(h2)),
+                                w["router"])
+            gates, idx = np.asarray(gates), np.asarray(idx)
+        dt = time.perf_counter() - th
+        rec.route_s += dt
+        rec.host_s += dt
+
+        th = time.perf_counter()
+        with span("experts", "session", layer=li,
+                  experts=len(np.unique(idx))) as sp:
+            names = [["shared"] + [f"e{e}" for e in row] for row in idx]
+            ups, _, r1 = self._group(
+                li, [[(n + ".up", h) for n in ns]
+                     for ns, h in zip(names, h2)], streams)
+            downs, _, r2 = self._group(
+                li, [[(n + ".down", u) for n, u in zip(ns, us)]
+                     for ns, us in zip(names, ups)], streams)
+            first = getattr(r1[0], "record", None)
+            sp.tag(req=first and first.request_id,
+                   requests=len(r1) + len(r2))
+        dt = time.perf_counter() - th
+        rec.pim_s["experts"] += dt
+        rec.expert_requests += len(r1) + len(r2)
+
+        th = time.perf_counter()
+        xs = [_combine(x, jnp.asarray(ys[0]), jnp.asarray(np.stack(ys[1:])),
+                       jnp.asarray(g))
+              for x, ys, g in zip(xs, downs, gates)]
+        rec.host_s += time.perf_counter() - th
+        return xs
 
     # -- public API ------------------------------------------------------------
 
@@ -274,7 +475,7 @@ class DecodeEngine:
         prompts = np.asarray(prompts, np.int32)
         B, S = prompts.shape
         streams = [_Stream(f"stream-{b}", self.cfg, S + max_new,
-                           prompts[b, 0]) for b in range(B)]
+                           prompts[b, :1]) for b in range(B)]
         toks = prompts[:, 0]
         # host math at the GEMV phases' full float32 (prim.common.PRECISION)
         # and the reference's: a TPU's default runs bfloat16 passes
@@ -285,7 +486,116 @@ class DecodeEngine:
                 toks = prompts[:, i + 1] if i + 1 < S else nxt
                 for s, t in zip(streams, toks):
                     s.tokens.append(int(t))
+                    s.pos += 1
         return np.asarray([s.tokens for s in streams], np.int32)
+
+    def prefill(self, prompts: Sequence[Sequence[int]], max_len: int,
+                block: int | None = None) -> None:
+        """Open one stream per prompt and build its cache from every token
+        of the prompt but the last in one block forward on the device —
+        the engine's layer math against the pinned weights, each token
+        through all of its top-k experts — so that the next :meth:`step`
+        feeds each prompt's last token.  Prompts are padded to ``block``
+        positions (default: the power of two over the longest), so that
+        one compile serves prompts of every length; ``max_len`` bounds
+        prompt plus generated tokens.  Latent-attention models only."""
+        cfg = self.cfg
+        if not cfg.kv_lora_rank:
+            raise ValueError("prefill() builds latent caches: "
+                             f"{cfg.name} has no latent attention")
+        prompts = [np.asarray(p, np.int32) for p in prompts]
+        n_pre = [len(p) - 1 for p in prompts]
+        P = block or _bucket(max(n_pre))
+        if max(n_pre) > P or P > max_len or min(n_pre) < 1:
+            raise ValueError(f"prompts of {min(n_pre) + 1}..{max(n_pre) + 1}"
+                             f" tokens do not fit block {P} / max_len "
+                             f"{max_len}")
+        base = len(self.streams)
+        streams = [_Stream(f"stream-{base + b}", cfg, max_len, p)
+                   for b, p in enumerate(prompts)]
+        with span("prefill", "session", requests=len(streams)), \
+                jax.default_matmul_precision("highest"):
+            xs = [self.embed[jnp.asarray(np.pad(p[:n], (0, P - n)))]
+                  for p, n in zip(prompts, n_pre)]
+            for li, lw in enumerate(self.layers):
+                w = self._prefill_weights(li, lw)
+                ms = tuple(self._rows(li, k) for k in ("q", "kv_a", "o"))
+                for b, s in enumerate(streams):
+                    xs[b], s.caches[li] = _prefill_attn(
+                        cfg, ms, xs[b], w, s.caches[li])
+                if not lw.n_experts:
+                    ms = (self._rows(li, "up"), self._rows(li, "down"))
+                    xs = [_prefill_dense(cfg, ms, x, w) for x in xs]
+                    continue
+                ms = (self._rows(li, "shared.up"),
+                      self._rows(li, "shared.down"))
+                xs = [self._prefill_experts(li, lw, w, ms, x, n)
+                      for x, n in zip(xs, n_pre)]
+            jax.block_until_ready(xs)
+        for s, n in zip(streams, n_pre):
+            s.pos = n
+        self.streams += streams
+
+    def _prefill_weights(self, li: int, lw: LayerWeights) -> dict:
+        """Layer ``li``'s host arrays and the row chunks of its attention,
+        dense-FFN and shared-expert matvecs, as the device holds them."""
+        w = dict(self.host[li])
+        for proj in lw.mats:
+            if not proj.startswith("e"):
+                w[proj.removeprefix("shared.")] = self._device_rows(li, proj)
+        return w
+
+    def _device_rows(self, li: int, proj: str) -> list:
+        """Matvec ``proj``'s row chunks on the device: the pinned entry's
+        buffers where one bank holds them all, else the host operand put
+        on the device as one chunk."""
+        fp = self.pinned.get((li, proj))
+        ent = (self.session.cache.lookup(fp)
+               if fp is not None and self.session.n_banks == 1 else None)
+        if ent is not None and ent.ready:
+            return [ent.get(g) for g in range(ent.expected_chunks)]
+        op = self.handles[(li, proj)].value
+        a, b = (op["w"], op["b"]) if "w" in op else (op["wg"], op["wu"])
+        return [(jnp.asarray(a)[None], jnp.asarray(b)[None])]
+
+    def _prefill_experts(self, li, lw, w, ms, x, n):
+        """An MoE layer's FFN over one stream's block: the shared experts,
+        then each routed expert over the real positions routed to it."""
+        x, h, gates, idx = _prefill_moe(self.cfg, ms, x, w)
+        idx, gates = np.asarray(idx)[:n], np.asarray(gates)[:n]
+        k = idx.shape[1]
+        tok = np.repeat(np.arange(n, dtype=np.int32), k)
+        ex, gw = idx.reshape(-1), gates.reshape(-1)
+        order = np.argsort(ex, kind="stable")
+        ends = np.cumsum(np.bincount(ex, minlength=lw.n_experts))
+        ems = (self._rows(li, "e0.up"), self._rows(li, "e0.down"))
+        for e in range(lw.n_experts):
+            sel = order[ends[e - 1] if e else 0:ends[e]]
+            if not len(sel):
+                continue
+            m = _bucket(len(sel))
+            rows = np.zeros(m, np.int32)
+            g = np.zeros(m, np.float32)
+            rows[:len(sel)], g[:len(sel)] = tok[sel], gw[sel]
+            x = _prefill_expert(
+                ems, x, h, jnp.asarray(rows), jnp.asarray(g),
+                self._device_rows(li, f"e{e}.up"),
+                self._device_rows(li, f"e{e}.down"))
+        return x
+
+    def step(self) -> np.ndarray:
+        """Every open stream (:meth:`prefill`) advances one token: each
+        feeds its last token and appends its greedy next one, returned as
+        (B,) int32; the step's logits stay in :attr:`last_logits`."""
+        streams = self.streams
+        toks = np.asarray([s.tokens[-1] for s in streams], np.int32)
+        with jax.default_matmul_precision("highest"):
+            nxt = self._step(streams, toks, step=len(self.steps),
+                             generated=True)
+        for s, t in zip(streams, nxt):
+            s.tokens.append(int(t))
+            s.pos += 1
+        return nxt
 
     def report(self) -> dict:
         """Serving metrics over every step so far: tokens/sec and
@@ -296,7 +606,7 @@ class DecodeEngine:
         pre = [s for s in self.steps if not s.tokens]
         gen_wall = sum(s.wall_s for s in gen)
         new_tokens = sum(s.tokens for s in gen)
-        pim_s = dict.fromkeys(PIM_GROUPS, 0.0)
+        pim_s = dict.fromkeys(self.groups, 0.0)
         for s in self.steps:
             for k, v in s.pim_s.items():
                 pim_s[k] += v
