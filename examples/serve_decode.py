@@ -73,7 +73,7 @@ if __name__ == "__main__":
     ap.add_argument("--ranks", type=int, default=0,
                     help="rank count for rank-sharded matvecs (0 = flat)")
     ap.add_argument("--streams", type=int, default=4,
-                    help="concurrent decode streams (one tenant each)")
+                    help="concurrent decode streams (stacked in each request)")
     ap.add_argument("--layers", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=12)
     ap.add_argument("--max-new", type=int, default=20)

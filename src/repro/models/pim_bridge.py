@@ -46,10 +46,11 @@ class LayerWeights:
     """One decoder layer's PIM-side operands + host-side arrays.
 
     ``mats`` maps each matvec of the layer to its operand pytree: GEMV-B
-    ``{"w", "b"}`` or, for a SwiGLU's gated half (a name ending in
-    ``up``), GEMV-G ``{"wg", "wu"}``.  Each pytree is what the engine wraps
-    in one :class:`~repro.runtime.resident.ResidentHandle` and pins as a
-    unit.  The names:
+    ``{"w", "b"}`` (``b`` a column, ``(rows, 1)``) or, for a SwiGLU's
+    gated half (a name ending in ``up``), GEMV-G ``{"wg", "wu"}``.  Each
+    pytree is what the engine wraps in one
+    :class:`~repro.runtime.resident.ResidentHandle` and pins as a unit.
+    The names:
 
     * attention: ``q``, ``k``, ``v``, ``o``; latent attention (MLA):
       ``q``, ``kv_a`` (the compressed KV and the rope key), ``o``;
@@ -124,9 +125,11 @@ def _bias(p: dict, key: str, n: int) -> np.ndarray:
 
 
 def _gemv_b(w, b=None) -> dict:
+    """GEMV-B's operand, the bias a column: the engine stacks its streams'
+    vectors as columns."""
     w = _rows(w)
-    return {"w": w, "b": np.zeros(w.shape[0], np.float32) if b is None
-            else b}
+    b = np.zeros(w.shape[0], np.float32) if b is None else b
+    return {"w": w, "b": b.reshape(-1, 1)}
 
 
 def _swiglu(ffn: dict, prefix: str) -> dict:

@@ -18,15 +18,21 @@ subsystems rather than beside them:
 * **rank-sharded matvecs** — the pinned GEMV-B / GEMV-G chunks are output
   *rows*; on a ranked session (``ranks=R``) the contiguous chunk blocks
   shard attention heads and FFN columns across ranks (DESIGN.md §10);
-* **multi-stream serving** — each decode stream is its own tenant; every
-  step submits each projection for all streams in one group, so the
-  scheduler's weighted-fair dispatch and same-tenant q/k/v coalescing
-  apply (DESIGN.md §13).  ``step_deadline_s`` stamps each group's requests
-  with a deadline for QoS experiments;
+* **multi-stream serving** — streams are the unit of traffic, each with
+  its own cache, tokens and latency, advancing in lockstep; a step sends
+  one request a weight matrix, its vector operand a column per stream
+  that needs the matrix (every stream for an attention or dense FFN
+  matrix, the streams that chose it for a routed expert), so the matrix
+  is read once for all of them.  The requests go to the engine's one
+  tenant, where consecutive ones coalesce into a chunk-pipeline batch;
+  weighted-fair dispatch orders the engine against other tenants, not
+  the streams inside a step (DESIGN.md §13).  ``step_deadline_s`` stamps
+  each request with a deadline for QoS experiments;
 * **phase accounting** — every request is tagged ``layer=i,
-  proj=q|k|v|o|up|down`` (telemetry rows grow ``tag_*`` columns, trace
-  ``serve`` spans carry the labels), and each step keeps an independent
-  engine-side :class:`StepRecord` of where its wall time went.
+  proj=q|k|v|o|up|down`` and ``streams=n`` (telemetry rows grow ``tag_*``
+  columns, trace ``serve`` spans carry the labels), and each step keeps
+  an independent engine-side :class:`StepRecord` of where its wall time
+  went.
 
 Host/PIM split per layer (the host math is the model's own jnp functions,
 so tokens match :func:`repro.launch.serve.greedy_generate` exactly):
@@ -41,8 +47,8 @@ same way, with the routing on the host:
     host: rms_norm ─ PIM: q, kv_a ─ host: MLA over the latent cache
     (``wkv_b`` absorbed) ─ PIM: o ─ host: residual + rms_norm + router
     (softmax, top-k, no renormalisation unless the model says so, no
-    capacity) ─ PIM: gate|up of the shared and the k chosen experts ─ PIM:
-    their downs ─ host: residual + shared + Σ gate·expert
+    capacity) ─ PIM: gate|up of the shared and of every chosen expert ─
+    PIM: their downs ─ host: residual + shared + Σ gate·expert
 
 :meth:`DecodeEngine.prefill` builds the streams' latent caches from whole
 prompts in one block forward on the device, against the same pinned
@@ -75,6 +81,9 @@ from .session import PimSession, session as open_session
 PROJ_WORKLOADS = {"q": "GEMV-B", "k": "GEMV-B", "v": "GEMV-B",
                   "o": "GEMV-B", "up": "GEMV-G", "down": "GEMV-B"}
 
+#: the session tenant of every engine request
+TENANT = "decode"
+
 #: engine-measured step phases: the PIM groups + everything else ("qkv"
 #: is MLA's q + kv_a; a model with MoE layers adds "experts", their shared
 #: and routed experts)
@@ -98,11 +107,14 @@ class StepRecord:
     route_s: float = 0.0
     attend_s: float = 0.0
     experts_s: float = 0.0
+    requests: int = 0
+    matvecs: int = 0
+    expert_batches: int = 0
     expert_requests: int = 0
 
 
 class _Stream:
-    """One decode stream: its tenant name, its tokens (the last one is fed
+    """One decode stream: its name, its tokens (the last one is fed
     next) and per-layer caches on the host device — ``attention.init_cache``'s
     layout, or for MLA the latent ``c`` (T, r) and rope key ``pe``
     (T, rope) with ``pos`` positions filled."""
@@ -158,8 +170,8 @@ def _combine(x, shared, ys, gates):
 
 def _mv_rows(x, chunks, m):
     """x (S, d_in) against a GEMV-B operand's row chunks [(w (1, per, d_in),
-    b (1, per))]: (S, m)."""
-    y = jnp.concatenate([x @ w[0].T + b[0] for w, b in chunks], -1)
+    b (1, per, 1))]: (S, m)."""
+    y = jnp.concatenate([x @ w[0].T + b[0].T for w, b in chunks], -1)
     return y[:, :m]
 
 
@@ -275,32 +287,38 @@ class DecodeEngine:
         op = self.handles[(li, proj)].value
         return (op["w"] if "w" in op else op["wg"]).shape[0]
 
-    # -- one group of matvecs across all streams -------------------------------
+    # -- one group of matvecs, one request a matrix ---------------------------
 
-    def _group(self, li: int, items: Sequence[Sequence[tuple[str, object]]],
-               streams: Sequence[_Stream]) -> tuple[list, float, list]:
-        """Submit ``items[b]`` — (proj, vector) pairs — for every stream
-        ``b``, run the group to completion, and return (results per stream
-        in item order, group wall seconds, the requests).  Same-tenant
-        consecutive submissions of one workload coalesce into one
-        chunk-pipeline batch."""
+    def _group(self, li: int, jobs: Sequence[tuple[str, Sequence]],
+               width: int, rec: StepRecord) -> tuple[list, float, list]:
+        """Submit one request per job ``(proj, vectors)`` of layer ``li``,
+        the vectors stacked as columns and padded with zero columns to
+        ``width`` (the step's stream count, so that each matrix compiles
+        at one shape), run the group to completion, and return (per job
+        the result vectors in the order of its vectors, group wall
+        seconds, the requests).
+        Consecutive requests of one workload coalesce into one
+        chunk-pipeline batch (one tenant: the engine's)."""
         t0 = time.perf_counter()
         reqs = []
-        for s, its in zip(streams, items):
-            for proj, vec in its:
-                opts = RequestOptions(tenant=s.name,
-                                      deadline_s=self.step_deadline_s,
-                                      tags={"layer": li, "proj": proj})
-                reqs.append(self.session.submit(
-                    workload_of(proj), self.handles[(li, proj)],
-                    np.asarray(vec, np.float32), options=opts))
+        for proj, vecs in jobs:
+            x = np.zeros((len(vecs[0]), width), np.float32)
+            x[:, :len(vecs)] = np.stack(vecs, 1)
+            opts = RequestOptions(tenant=TENANT,
+                                  deadline_s=self.step_deadline_s,
+                                  tags={"layer": li, "proj": proj,
+                                        "streams": len(vecs)})
+            reqs.append(self.session.submit(
+                workload_of(proj), self.handles[(li, proj)], x,
+                options=opts))
         if not self.session.serving:
             self.session.drain()
-        flat = [r.result() for r in reqs]
-        out, i = [], 0
-        for its in items:
-            out.append(flat[i:i + len(its)])
-            i += len(its)
+        out = []
+        for r, (_, vecs) in zip(reqs, jobs):
+            y = r.result()
+            out.append([y[:, j] for j in range(len(vecs))])
+        rec.requests += len(reqs)
+        rec.matvecs += sum(len(v) for _, v in jobs)
         return out, time.perf_counter() - t0, reqs
 
     # -- attention host halves --------------------------------------------------
@@ -353,6 +371,7 @@ class DecodeEngine:
                          wall_s=0.0, pim_s=dict.fromkeys(self.groups, 0.0),
                          host_s=0.0)
         pim_s = rec.pim_s
+        B = len(streams)
 
         th = time.perf_counter()
         xs = [self.embed[jnp.asarray(t).reshape(1, 1)]
@@ -367,46 +386,45 @@ class DecodeEngine:
             rec.host_s += time.perf_counter() - th
 
             if cfg.kv_lora_rank:
-                qkv, dt, _ = self._group(li, [[("q", h), ("kv_a", h)]
-                                              for h in hv], streams)
+                qkv, dt, _ = self._group(li, [("q", hv), ("kv_a", hv)], B,
+                                         rec)
                 pim_s["qkv"] += dt
                 th = time.perf_counter()
                 with span("mla", "session", layer=li):
                     ov = [self._attend_mla(s, li, *r)
-                          for s, r in zip(streams, qkv)]
+                          for s, *r in zip(streams, *qkv)]
                 dt = time.perf_counter() - th
                 rec.attend_s += dt
             else:
-                qkv, dt, _ = self._group(li, [[("q", h), ("k", h), ("v", h)]
-                                              for h in hv], streams)
+                qkv, dt, _ = self._group(li, [("q", hv), ("k", hv),
+                                              ("v", hv)], B, rec)
                 pim_s["qkv"] += dt
                 th = time.perf_counter()
-                ov = [self._attend(s, li, *r) for s, r in zip(streams, qkv)]
+                ov = [self._attend(s, li, *r)
+                      for s, *r in zip(streams, *qkv)]
                 dt = time.perf_counter() - th
             rec.host_s += dt
 
-            mo, dt, _ = self._group(li, [[("o", o)] for o in ov], streams)
+            (mo,), dt, _ = self._group(li, [("o", ov)], B, rec)
             pim_s["o"] += dt
 
             th = time.perf_counter()
-            xs = [x + jnp.asarray(m[0]).reshape(1, 1, d)
+            xs = [x + jnp.asarray(m).reshape(1, 1, d)
                   for x, m in zip(xs, mo)]
             h2 = [np.asarray(rms_norm(x, w["norm2"], eps)).reshape(-1)
                   for x in xs]
             rec.host_s += time.perf_counter() - th
 
             if lw.n_experts:
-                xs = self._moe(li, xs, h2, streams, rec)
+                xs = self._moe(li, xs, h2, rec)
                 continue
-            hidden, dt, _ = self._group(li, [[("up", h)] for h in h2],
-                                        streams)
+            (hidden,), dt, _ = self._group(li, [("up", h2)], B, rec)
             pim_s["up"] += dt
-            down, dt, _ = self._group(li, [[("down", h[0])]
-                                           for h in hidden], streams)
+            (down,), dt, _ = self._group(li, [("down", hidden)], B, rec)
             pim_s["down"] += dt
 
             th = time.perf_counter()
-            xs = [x + jnp.asarray(dn[0]).reshape(1, 1, d)
+            xs = [x + jnp.asarray(dn).reshape(1, 1, d)
                   for x, dn in zip(xs, down)]
             rec.host_s += time.perf_counter() - th
 
@@ -427,10 +445,13 @@ class DecodeEngine:
                     generated=int(generated))
         return nxt
 
-    def _moe(self, li: int, xs, h2, streams, rec: StepRecord) -> list:
-        """Route on the host, then the shared and the chosen experts of
-        every stream as one group: gate|up requests, then downs."""
+    def _moe(self, li: int, xs, h2, rec: StepRecord) -> list:
+        """Route on the host, then one gate|up request for the shared
+        experts over every stream and one for each chosen expert over the
+        streams that chose it, then their downs; each stream sums its
+        experts in its own top-k order."""
         cfg, w = self.cfg, self.host[li]
+        B = len(h2)
         th = time.perf_counter()
         with span("route", "session", layer=li):
             gates, idx = _route(cfg.moe_top_k, jnp.asarray(np.stack(h2)),
@@ -441,26 +462,36 @@ class DecodeEngine:
         rec.host_s += dt
 
         th = time.perf_counter()
-        with span("experts", "session", layer=li,
-                  experts=len(np.unique(idx))) as sp:
-            names = [["shared"] + [f"e{e}" for e in row] for row in idx]
+        experts = np.unique(idx)
+        # the streams of each chosen expert, in stream order
+        users = [np.flatnonzero((idx == e).any(1)) for e in experts]
+        matvecs = 2 * (B + sum(len(u) for u in users))
+        with span("experts", "session", layer=li, experts=len(experts),
+                  matvecs=matvecs) as sp:
             ups, _, r1 = self._group(
-                li, [[(n + ".up", h) for n in ns]
-                     for ns, h in zip(names, h2)], streams)
+                li, [("shared.up", h2)] + [
+                    (f"e{e}.up", [h2[b] for b in u])
+                    for e, u in zip(experts, users)], B, rec)
             downs, _, r2 = self._group(
-                li, [[(n + ".down", u) for n, u in zip(ns, us)]
-                     for ns, us in zip(names, ups)], streams)
+                li, [("shared.down", ups[0])] + [
+                    (f"e{e}.down", y) for e, y in zip(experts, ups[1:])],
+                B, rec)
             first = getattr(r1[0], "record", None)
             sp.tag(req=first and first.request_id,
                    requests=len(r1) + len(r2))
         dt = time.perf_counter() - th
         rec.pim_s["experts"] += dt
-        rec.expert_requests += len(r1) + len(r2)
+        rec.expert_batches += len(r1) + len(r2)
+        rec.expert_requests += matvecs
 
         th = time.perf_counter()
-        xs = [_combine(x, jnp.asarray(ys[0]), jnp.asarray(np.stack(ys[1:])),
-                       jnp.asarray(g))
-              for x, ys, g in zip(xs, downs, gates)]
+        # stream b's output of expert e: its place among e's streams
+        out = {(e, b): y for e, u, ys in zip(experts, users, downs[1:])
+               for b, y in zip(u, ys)}
+        xs = [_combine(x, jnp.asarray(downs[0][b]),
+                       jnp.asarray(np.stack([out[e, b] for e in idx[b]])),
+                       jnp.asarray(gates[b]))
+              for b, x in enumerate(xs)]
         rec.host_s += time.perf_counter() - th
         return xs
 

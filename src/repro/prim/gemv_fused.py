@@ -21,6 +21,14 @@ Row chunks are the pipeline's chunks (and the residency chunks): on a
 RankGrid the contiguous chunk blocks shard output rows — attention heads,
 FFN columns — across ranks, so a warm decode step scatters only the
 activation vector broadcast.
+
+The vector operand is either one vector ``(d,)`` or a stack of them as
+columns ``(d, n)``, and the result is ``(rows,)`` or ``(rows, n)`` to
+match: the operand's ``ndim`` picks the shape inside the same compute
+phase, so the matrix is read once for every column.  GEMV-B's bias
+broadcasts against ``W @ x`` as numpy has it: ``(rows,)`` for one vector,
+a column ``(rows, 1)`` for a stack.  The decode engine sends one stack a
+weight matrix a step, a column per stream that needs it.
 """
 from __future__ import annotations
 
@@ -40,6 +48,11 @@ from .common import (ChunkedWorkload, PhaseTimer, matvec, pad_chunks,
 def _silu_f32(g):
     """silu in float32, cast back — the swiglu gate's exact numerics."""
     return jax.nn.silu(g.astype(jnp.float32)).astype(g.dtype)
+
+
+def _rows(out: np.ndarray) -> np.ndarray:
+    """A banked result (banks, per, [n]) as rows (banks * per, [n])."""
+    return out.reshape(-1, *out.shape[2:])
 
 
 def _bias_mv(wb, bb, xb):
@@ -69,7 +82,7 @@ def pim_b(grid: BankGrid, w: dict, x: np.ndarray):
     with t.phase("dpu"):
         out = sync(f(dw, db, dx))
     with t.phase("dpu_cpu"):
-        host = grid.from_banks(out).reshape(-1)[:m]
+        host = _rows(grid.from_banks(out))[:m]
     return host, t.times
 
 
@@ -108,7 +121,7 @@ def _compute_b(grid, meta, bufs):
 
 
 def _retrieve_b(grid, meta, out):
-    return grid.from_banks(out).reshape(-1)[:meta["per"]]
+    return _rows(grid.from_banks(out))[:meta["per"]]
 
 
 def _merge_b(grid, meta, parts):
@@ -142,7 +155,7 @@ def pim_g(grid: BankGrid, w: dict, x: np.ndarray):
     with t.phase("dpu"):
         out = sync(f(dg, du, dx))
     with t.phase("dpu_cpu"):
-        host = grid.from_banks(out).reshape(-1)[:m]
+        host = _rows(grid.from_banks(out))[:m]
     return host, t.times
 
 
@@ -181,7 +194,7 @@ def _compute_g(grid, meta, bufs):
 
 
 def _retrieve_g(grid, meta, out):
-    return grid.from_banks(out).reshape(-1)[:meta["per"]]
+    return _rows(grid.from_banks(out))[:meta["per"]]
 
 
 def _merge_g(grid, meta, parts):

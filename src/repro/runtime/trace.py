@@ -343,7 +343,7 @@ class _LayerSpan:
 
 def span(name: str, cat: str = "", *, req=None, batch=None, chunk=None,
          workload=None, bytes=None, requests=None, fingerprint=None,
-         layer=None, experts=None, track=None):
+         layer=None, experts=None, matvecs=None, track=None):
     """``with span("scatter", "cpu_dpu", req=7, chunk=0): ...`` — one span
     to both sinks (module docstring).  With no profiler recording and the
     tracer off it returns the shared :data:`NULL_SPAN` and builds nothing.
@@ -355,7 +355,8 @@ def span(name: str, cat: str = "", *, req=None, batch=None, chunk=None,
                               ("chunk", chunk), ("workload", workload),
                               ("bytes", bytes), ("requests", requests),
                               ("fingerprint", fingerprint),
-                              ("layer", layer), ("experts", experts))
+                              ("layer", layer), ("experts", experts),
+                              ("matvecs", matvecs))
             if v is not None}
     return _LayerSpan(name, cat, track, tags, tracer)
 

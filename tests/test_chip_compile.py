@@ -1,7 +1,8 @@
 """Compiles for a described TPU v5e chip: the Pallas kernels that serialized
 ``pim()`` reaches, at the shapes ``kernels/ops.py`` pads to, the chunked
-GEMV-B / GEMV-G compute phases at TinyLlama 1.1B widths, and the chunked HST
-phase at the benchmark's chunk size.
+GEMV-B / GEMV-G compute phases at TinyLlama 1.1B widths and, with a stack of
+vectors, at DeepSeek-V2-Lite's expert widths, and the chunked HST phase at
+the benchmark's chunk size.
 
 Nothing runs: the TPU compiler that ships with JAX compiles for a chip that
 is described, not attached, and raises what the chip's compiler would raise
@@ -141,6 +142,27 @@ def test_gemv_g_phase_compiles(bank_grid):
     fn = gemv_fused._local_g(bank_grid)
     lowered = fn.lower(w, w, _spec((2048,), jnp.float32,
                                    bank_grid.sharding(P())))
+    assert "HIGHEST" in lowered.as_text()
+    lowered.compile()
+
+
+#: DeepSeek-V2-Lite's expert matvecs (one of two row chunks), the vectors
+#: of the decode cell's 8 streams stacked as columns (GEMV-B's bias a
+#: column)
+STACKED = {"GEMV-B": (1024, 1408), "GEMV-G": (704, 2048)}
+
+
+@pytest.mark.parametrize("workload", sorted(STACKED))
+def test_stacked_gemv_phase_compiles(bank_grid, workload):
+    rows, d_in = STACKED[workload]
+    banked = bank_grid.sharding(P(AXIS))
+    w = _spec((1, rows, d_in), jnp.float32, banked)
+    x = _spec((d_in, 8), jnp.float32, bank_grid.sharding(P()))
+    if workload == "GEMV-B":
+        lowered = gemv_fused._local_b(bank_grid).lower(
+            w, _spec((1, rows, 1), jnp.float32, banked), x)
+    else:
+        lowered = gemv_fused._local_g(bank_grid).lower(w, w, x)
     assert "HIGHEST" in lowered.as_text()
     lowered.compile()
 

@@ -22,7 +22,7 @@ from repro.configs import get_config
 from repro.launch import serve as serve_mod
 from repro.models import transformer
 from repro.models.pim_bridge import validate_decode_config
-from repro.pim.decode import PIM_GROUPS, PROJ_WORKLOADS, DecodeEngine
+from repro.pim.decode import PIM_GROUPS, PROJ_WORKLOADS, TENANT, DecodeEngine
 from repro.runtime.elastic import carve_mesh
 from repro.runtime.trace import NULL_TRACER, set_tracer
 
@@ -102,14 +102,19 @@ def test_telemetry_rows_tag_every_layer_and_projection(decode_run):
     n_banks = decode_run.session.n_banks
     rows = [r.row(n_banks) for r in decode_run.session.telemetry.records]
     tagged = [r for r in rows if "tag_proj" in r]
-    # every step submits all 6 projections x n_layers x streams
+    # every step submits one request per projection x n_layers, each
+    # carrying every stream's vector, under the engine's tenant
     assert len(tagged) == ((PROMPT + MAX_NEW - 1) * cfg.n_layers
-                           * len(PROJ_WORKLOADS) * STREAMS)
+                           * len(PROJ_WORKLOADS))
     assert {r["tag_proj"] for r in tagged} == set(PROJ_WORKLOADS)
     assert {r["tag_layer"] for r in tagged} == set(range(cfg.n_layers))
     for r in tagged:
         assert r["workload"] == PROJ_WORKLOADS[r["tag_proj"]]
-        assert r["tenant"].startswith("stream-")
+        assert r["tag_streams"] == STREAMS
+        assert r["tenant"] == TENANT
+    for sr in eng.steps:
+        assert sr.requests == cfg.n_layers * len(PROJ_WORKLOADS)
+        assert sr.matvecs == STREAMS * sr.requests
 
 
 def test_serve_spans_carry_the_phase_tags(decode_run):
@@ -117,7 +122,8 @@ def test_serve_spans_carry_the_phase_tags(decode_run):
     tagged = [sp for sp in serves if "proj" in sp.args]
     assert tagged, "no tagged serve spans"
     assert {sp.args["proj"] for sp in tagged} == set(PROJ_WORKLOADS)
-    assert all(sp.args["tenant"].startswith("stream-") for sp in tagged)
+    assert all(sp.args["tenant"] == TENANT and sp.args["streams"] == STREAMS
+               for sp in tagged)
 
 
 # -- residency: warm steps move activations only ------------------------------

@@ -50,28 +50,50 @@ def model():
     return params, reference_params(params, SMOKE)
 
 
+def _prompts():
+    rng = np.random.default_rng(0)
+    return [rng.integers(0, SMOKE.vocab, n) for n in (9, 13, 5)]
+
+
+def _serve(params, prompts, steps=6):
+    """An engine's streams block-prefilled from ``prompts``, then ``steps``
+    lockstep decode steps: (engine, logits (B, steps, V), the backend
+    compiles of each step after the first)."""
+    compiles, counts = [], []
+
+    def listen(event, _duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        with pim.session(banks=1, n_chunks=2) as s:
+            eng = DecodeEngine(params, SMOKE, session=s)
+            eng.prefill(prompts, max_len=32)
+            logits = []
+            for _ in range(steps):
+                compiles.clear()
+                eng.step()
+                counts.append(len(compiles))
+                logits.append(np.asarray(eng.last_logits))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    return eng, np.stack(logits, 1), counts[1:]
+
+
 @pytest.fixture(scope="module")
 def served(model):
     """Three streams block-prefilled from prompts of 9, 13 and 5 tokens,
-    then 6 lockstep decode steps, logits kept."""
+    then 6 lockstep decode steps, logits kept, and the compiles of every
+    step after the first."""
     params, _ = model
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(0, SMOKE.vocab, n) for n in (9, 13, 5)]
-    with pim.session(banks=1, n_chunks=2) as s:
-        eng = DecodeEngine(params, SMOKE, session=s)
-        eng.prefill(prompts, max_len=32)
-        logits = []
-        for _ in range(6):
-            eng.step()
-            logits.append(np.asarray(eng.last_logits))
-    return eng, np.stack(logits, 1)                   # (B, steps, V)
+    return _serve(params, _prompts())
 
 
 # -- engine vs the plain reference ---------------------------------------------
 
 def test_engine_logits_match_reference_after_prefill(model, served):
     _, rp = model
-    eng, logits = served
+    eng, logits, _ = served
     want = _ref_last(rp, [s.tokens[:-1] for s in eng.streams],
                      logits.shape[1])
     for b, w in enumerate(want):
@@ -83,7 +105,7 @@ def test_greedy_tokens_match_reference_greedy(model, served):
     prefix is the engine's next token: the reference decoding greedily
     from the prompts gives the engine's tokens."""
     _, rp = model
-    eng, logits = served
+    eng, logits, _ = served
     steps = logits.shape[1]
     want = _ref_last(rp, [s.tokens[:-1] for s in eng.streams], steps)
     for s, w in zip(eng.streams, want):
@@ -93,7 +115,7 @@ def test_greedy_tokens_match_reference_greedy(model, served):
 def test_generate_token_by_token_matches_prefill(model, served):
     """``generate`` (no block prefill) yields the tokens of prefill + step."""
     params, _ = model
-    eng, logits = served
+    eng, logits, _ = served
     s0 = eng.streams[0]
     n = len(s0.tokens) - logits.shape[1]
     with pim.session(banks=1, n_chunks=2) as s:
@@ -103,7 +125,7 @@ def test_generate_token_by_token_matches_prefill(model, served):
 
 
 def test_expert_groups_are_counted(served):
-    eng, _ = served
+    eng, _, _ = served
     k = SMOKE.moe_top_k
     for st in eng.steps:
         assert st.expert_requests == 3 * (1 + k) * 2
@@ -111,6 +133,68 @@ def test_expert_groups_are_counted(served):
         assert 0 < st.route_s and 0 < st.attend_s < st.host_s
     # 6 + 2 * experts handles in each MoE layer, 5 in the dense one
     assert len(eng.pins) == 5 + 5 + 2 * SMOKE.moe_experts
+
+
+def _step_rows(eng):
+    """The engine's tagged requests, in submission order, cut into steps
+    (a step opens with layer 0's ``q``)."""
+    recs = sorted((r for r in eng.session.telemetry.records
+                   if "proj" in r.tags), key=lambda r: r.request_id)
+    steps = []
+    for r in recs:
+        if (r.tags["layer"], r.tags["proj"]) == (0, "q"):
+            steps.append([])
+        steps[-1].append(r.tags)
+    return steps
+
+
+def test_one_request_per_matrix_a_step(served):
+    """Each step sends one request per (layer, matrix) it uses: q, kv_a,
+    o and the FFN over all three streams, and each routed expert chosen
+    that step over the streams that chose it (each stream through k
+    distinct experts).  The per-stream counts are those of one request
+    per stream and matvec."""
+    eng, _, _ = served
+    k, B = SMOKE.moe_top_k, len(eng.streams)
+    per_stream = 5 + 3 + 2 * (1 + k)       # dense layer, then the MoE one
+    steps = _step_rows(eng)
+    assert len(steps) == len(eng.steps)
+    for st, tags in zip(eng.steps, steps):
+        keys = [(t["layer"], t["proj"]) for t in tags]
+        assert st.requests == len(keys) == len(set(keys))
+        assert st.matvecs == sum(t["streams"] for t in tags) \
+            == B * per_stream
+        routed = [t for t in tags if t["proj"][0] == "e"]
+        ups = {t["proj"][:-3] for t in routed if t["proj"].endswith(".up")}
+        downs = {t["proj"][:-5] for t in routed
+                 if t["proj"].endswith(".down")}
+        assert ups == downs and 0 < len(ups) <= B * k
+        assert st.expert_batches == 2 + 2 * len(ups)
+        assert sum(t["streams"] for t in routed) == 2 * B * k
+        assert all(t["streams"] == B for t in tags if t["proj"][0] != "e")
+        assert st.expert_requests == 2 * B + sum(t["streams"]
+                                                 for t in routed)
+
+
+def test_logits_match_per_stream_engines(model, served):
+    """Each stream alone in an engine (one request per stream and matvec)
+    gives the batched engine's logits to float32 rounding."""
+    params, _ = model
+    _, logits, _ = served
+    for b, p in enumerate(_prompts()):
+        _, alone, _ = _serve(params, [p], steps=logits.shape[1])
+        assert _rel(logits[b], alone[0]) < 1e-6, b
+
+
+def test_varying_expert_streams_compile_nothing(served):
+    """Routed experts carry 1 to 3 streams from step to step; operands
+    padded to the stream count keep every shape, so after the first step
+    nothing compiles."""
+    eng, _, compiles = served
+    counts = {t["streams"] for tags in _step_rows(eng)[1:] for t in tags
+              if t["proj"][0] == "e"}
+    assert len(counts) > 1, counts
+    assert compiles == [0] * (len(eng.steps) - 1)
 
 
 def test_model_path_matches_reference(model):

@@ -168,6 +168,113 @@ def test_hist_chunked_phase_has_no_scatter(bank_grid):
     assert "stablehlo.scatter" not in text
 
 
+# GEMV-B / GEMV-G with a stack of vectors (the decode engine's one request
+# per weight matrix): equal, to float32 rounding (a matrix-matrix product
+# sums in another order than a matvec), to one request per column, GEMV-B's
+# bias a column for the stack and a vector for the columns.  A row count
+# that divides by no chunking, pinned and sent operands, 1 to 3 chunks; the
+# 4-bank cases run in one subprocess, as for chunked HST.
+STACK_M, STACK_D, STACK_N = 37, 24, 5
+STACK_CASES = [(wl, k, op) for wl in ("GEMV-B", "GEMV-G")
+               for k in (1, 2, 3) for op in ("resident", "sent")]
+
+STACK_SCRIPT = r"""
+import sys; sys.path.insert(0, {src!r})
+import json
+sys.path.insert(0, {tests!r})
+from test_prim import STACK_CASES, stacked_gemv_case
+print(json.dumps([stacked_gemv_case(4, *c) for c in STACK_CASES]))
+"""
+
+
+def stacked_gemv_case(banks, workload, n_chunks, operand):
+    """One stacked request of ``workload`` against the column-by-column
+    requests on a session of ``banks`` banks: (shape, largest difference,
+    whether they agree, whether every request hit the pinned entry)."""
+    from repro import pim
+    rng = np.random.default_rng(n_chunks)
+    mats = ("w", "b") if workload == "GEMV-B" else ("wg", "wu")
+    w = {k: rng.normal(size=(STACK_M, STACK_D) if k != "b" else STACK_M)
+         .astype(np.float32) for k in mats}
+    stack = {k: v.reshape(-1, 1) if k == "b" else v for k, v in w.items()}
+    x = rng.normal(size=(STACK_D, STACK_N)).astype(np.float32)
+    resident = operand == "resident"
+    with pim.session(banks=banks, n_chunks=n_chunks,
+                     resident=resident) as s:
+        ops = [pim.ResidentHandle(o) if resident else o for o in (stack, w)]
+        if resident:
+            for op in ops:
+                s.pin(workload, op, np.zeros(STACK_D, np.float32))
+        before = s.stats()["cache"]["hits"] if resident else 0
+        y = s.run(workload, ops[0], x)
+        cols = np.stack([s.run(workload, ops[1], x[:, j])
+                         for j in range(STACK_N)], 1)
+        hits = (s.stats()["cache"]["hits"] - before == STACK_N + 1
+                if resident else True)
+    return (list(y.shape), float(np.abs(y - cols).max()),
+            bool(np.allclose(y, cols, rtol=1e-5, atol=1e-5)), hits)
+
+
+@pytest.fixture(scope="module")
+def stacked_on_4_banks():
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    script = STACK_SCRIPT.format(src=os.path.join(here, "..", "src"),
+                                 tests=here)
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return dict(zip(STACK_CASES, json.loads(out.stdout.splitlines()[-1])))
+
+
+@pytest.mark.parametrize("banks", [1, 4])
+@pytest.mark.parametrize("case", STACK_CASES,
+                         ids=lambda c: "-".join(map(str, c)))
+def test_stacked_gemv_equals_one_request_per_column(request, banks, case):
+    if banks == 1:
+        shape, err, close, hits = stacked_gemv_case(1, *case)
+    else:
+        shape, err, close, hits = request.getfixturevalue(
+            "stacked_on_4_banks")[case]
+    assert shape == [STACK_M, STACK_N]
+    assert close, err
+    assert hits
+
+
+def test_stacked_gemv_runs_in_the_matvec_phases(bank_grid, monkeypatch):
+    """A stack of vectors takes the same jitted phases as one vector
+    (``_local_b``/``_local_g``), so the device trace still names their
+    modules ``jit__bias_mv`` and ``jit__gated_mv``."""
+    from repro import pim
+    from repro.prim import gemv_fused
+    seen = []
+    real = {n: getattr(gemv_fused, n) for n in ("_local_b", "_local_g")}
+    for name, make in real.items():
+        def spy(grid, make=make, name=name):
+            fn = make(grid)
+
+            def call(*args):
+                seen.append((name, args[-1].ndim))
+                return fn(*args)
+            return call
+        monkeypatch.setattr(gemv_fused, name, spy)
+    rng = np.random.default_rng(0)
+    a = rng.normal(size=(9, 6)).astype(np.float32)
+    x = rng.normal(size=(6, 3)).astype(np.float32)
+    with pim.session(banks=1, n_chunks=2) as s:
+        s.run("GEMV-B", {"w": a, "b": a[:, :1]}, x)
+        s.run("GEMV-G", {"wg": a, "wu": a}, x)
+    assert set(seen) == {("_local_b", 2), ("_local_g", 2)}
+    w = bank_grid.to_banks(a[None])
+    b = bank_grid.to_banks(a[None, :, :1])
+    dx = bank_grid.broadcast(x)
+    assert real["_local_b"](bank_grid).lower(w, b, dx).as_text() \
+        .startswith("module @jit__bias_mv ")
+    assert real["_local_g"](bank_grid).lower(w, w, dx).as_text() \
+        .startswith("module @jit__gated_mv ")
+
+
 @pytest.mark.parametrize("via", ["host", "fabric"])
 def test_red(bank_grid, rng, via):
     x = rng.integers(0, 100, 5001).astype(np.int32)
