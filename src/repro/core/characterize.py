@@ -1,11 +1,9 @@
 """Microbenchmark harness — the paper's §3 characterization methodology.
 
 Each function mirrors one of the paper's microbenchmarks and returns rows of
-measurements taken on the *current JAX backend* (CPU in this container, TPU on
-real hardware).  The paired analytical predictions from
-:class:`repro.core.perfmodel.DpuModel` reproduce the paper's published curves;
-running both side by side is how `benchmarks/microbench.py` renders the
-Fig. 4-10 analogues.
+measurements taken on the *current JAX backend*: the DMA latency sweep
+(Fig. 6) and the calibration sweeps the autotuner and the cost model fit
+(DESIGN.md §8, §15).
 
 Measurement discipline: jit + warmup + block_until_ready, median of ``reps``.
 """
@@ -33,7 +31,7 @@ def _time(fn: Callable, *args, reps: int = 5) -> float:
     return float(np.median(ts))
 
 
-# -- §3.1 arithmetic throughput (Fig. 4) -------------------------------------
+# -- §3.1 arithmetic ops and data types (op_throughput_sweep) --------------
 
 _OPS = {
     "add": lambda x, s: x + s,
@@ -44,41 +42,6 @@ _OPS = {
 }
 _DTYPES = {"int32": jnp.int32, "int64": jnp.int64,
            "float": jnp.float32, "double": jnp.float64}
-
-
-def arith_throughput(op: str, dtype: str, lanes: int = 16,
-                     n: int = 1 << 20, reps: int = 5) -> dict:
-    """Streaming read-modify-write loop (paper Listing 1): x[i] op= scalar.
-
-    ``lanes`` is the tasklet analogue: number of independent streams the
-    backend may execute in parallel (shaped (lanes, n//lanes))."""
-    dt = _DTYPES[dtype]
-    x = jnp.ones((lanes, max(n // lanes, 1)), dt)
-    s = dt(3)
-    f = jax.jit(lambda v: _OPS[op](v, s))
-    sec = _time(f, x, reps=reps)
-    return {"op": op, "dtype": dtype, "lanes": lanes,
-            "mops": x.size / sec / 1e6, "seconds": sec}
-
-
-# -- §3.1.3 WRAM STREAM (Fig. 5) ---------------------------------------------
-
-def stream_wram(which: str, n: int = 1 << 20, reps: int = 5) -> dict:
-    """STREAM COPY/ADD/SCALE/TRIAD on widest-available integer elements."""
-    a = jnp.arange(n, dtype=jnp.int64)   # truncates to int32 w/o x64 — fine
-    b = a + 1
-    s = a.dtype.type(3)
-    item = a.dtype.itemsize
-    fns = {
-        "copy": (lambda: a + 0, 2 * item),      # ld + sd
-        "add": (lambda: a + b, 3 * item),       # 2 ld + sd
-        "scale": (lambda: a * s, 2 * item),
-        "triad": (lambda: a + b * s, 3 * item),
-    }
-    fn, bytes_per = fns[which]
-    f = jax.jit(fn)
-    sec = _time(lambda _: f(), None, reps=reps)
-    return {"stream": which, "mbps": n * bytes_per / sec / 1e6, "seconds": sec}
 
 
 # -- §3.2.1 DMA latency model (Fig. 6) ---------------------------------------
@@ -102,85 +65,6 @@ def fit_dma_model(rows: list[dict], freq_hz: float) -> tuple[float, float]:
     cycles = [r["seconds"] * freq_hz for r in rows]
     from .perfmodel import DpuModel
     return DpuModel.fit_dma(sizes, cycles)
-
-
-# -- §3.2.2 streaming MRAM (Fig. 7): copy with explicit staging --------------
-
-def stream_mram(which: str, n: int = 1 << 21, block: int = 1024,
-                reps: int = 3) -> dict:
-    """Streaming through blocked staging (MRAM→WRAM→MRAM analogue): the
-    array is processed in ``block``-byte chunks via dynamic slices."""
-    x = jnp.arange(n, dtype=jnp.int64)
-    elems = max(block // x.dtype.itemsize, 1)
-
-    def body(i, acc):
-        chunk = jax.lax.dynamic_slice(x, (i * elems,), (elems,))
-        if which == "copy-dma":
-            return acc + chunk[0] * 0
-        if which == "copy":
-            return acc + chunk[-1] * 0 + chunk[0] * 0
-        if which == "add":
-            return acc + jnp.sum(chunk)
-        if which == "scale":
-            return acc + jnp.sum(chunk * 3)
-        if which == "triad":
-            return acc + jnp.sum(chunk * 3 + chunk)
-        raise ValueError(which)
-
-    nblocks = n // elems
-    f = jax.jit(lambda: jax.lax.fori_loop(0, nblocks, body,
-                                          jnp.zeros((), x.dtype)))
-    sec = _time(lambda _: f(), None, reps=reps)
-    return {"stream": which, "block": block,
-            "mbps": n * x.dtype.itemsize / sec / 1e6, "seconds": sec}
-
-
-# -- §3.2.3 strided / random (Fig. 8) ----------------------------------------
-
-def strided_bandwidth(stride: int, mode: str = "coarse", n: int = 1 << 20,
-                      reps: int = 3) -> dict:
-    """Coarse: contiguous fetch then stride in fast memory (CPU cache-line /
-    DPU 1KB-DMA analogue). Fine: gather only the used elements."""
-    x = jnp.arange(n, dtype=jnp.int64)
-    item = x.dtype.itemsize
-    idx = jnp.arange(0, n, stride)
-    if mode == "coarse":
-        f = jax.jit(lambda v: v.reshape(-1, stride)[:, 0].sum()
-                    if stride > 1 else v.sum())
-        used_bytes = n * item         # full array is streamed
-    elif mode == "fine":
-        f = jax.jit(lambda v: v[idx].sum())
-        used_bytes = idx.size * item
-    elif mode == "random":
-        ridx = jax.random.permutation(jax.random.PRNGKey(0), n)[: n // stride]
-        f = jax.jit(lambda v: v[ridx].sum())
-        used_bytes = ridx.size * item
-    else:
-        raise ValueError(mode)
-    sec = _time(f, x, reps=reps)
-    return {"stride": stride, "mode": mode, "seconds": sec,
-            "effective_mbps": (n // stride) * item / sec / 1e6,
-            "raw_mbps": used_bytes / sec / 1e6}
-
-
-# -- §3.3 throughput vs operational intensity (Fig. 9) -----------------------
-
-def intensity_sweep(ops_per_elem: int, dtype: str = "float",
-                    n: int = 1 << 20, reps: int = 3) -> dict:
-    """Variable compute per element fetched — the roofline transition probe."""
-    dt = _DTYPES[dtype]
-    x = jnp.ones(n, dt)
-
-    def f(v):
-        acc = v
-        for _ in range(ops_per_elem):
-            acc = acc + v
-        return jnp.sum(acc)
-
-    sec = _time(jax.jit(f), x, reps=reps)
-    itemsize = jnp.dtype(dt).itemsize
-    return {"op_per_byte": ops_per_elem / itemsize, "dtype": dtype,
-            "mops": max(ops_per_elem, 1) * n / sec / 1e6, "seconds": sec}
 
 
 # -- autotune calibration sweeps (DESIGN.md §8) ------------------------------
@@ -302,25 +186,4 @@ def rank_parallel_sweep(grid, rank_counts=None, nbytes: int = 1 << 22,
                      "push_s": push_s, "pull_s": pull_s,
                      "push_gbps": total / push_s / 1e9,
                      "pull_gbps": total / pull_s / 1e9})
-    return rows
-
-
-# -- §3.4 CPU<->bank transfers (Fig. 10) -------------------------------------
-
-def transfer_sweep(grid: BankGrid, mb_per_bank: int = 4) -> list[dict]:
-    rows = []
-    n = grid.n_banks
-    buf = np.zeros((n, mb_per_bank << 20 >> 3), np.int64)
-    for kind, fn in (
-        ("cpu_dpu_parallel", lambda: tx.push_parallel(grid, buf)),
-        ("cpu_dpu_serial", lambda: tx.push_serial(grid, list(buf))),
-        ("cpu_dpu_broadcast", lambda: tx.push_broadcast(grid, buf[0])),
-    ):
-        _, rec = fn()
-        rows.append({"kind": kind, "banks": n, "nbytes": rec.nbytes,
-                     "gbps": rec.bandwidth / 1e9})
-    dev, _ = tx.push_parallel(grid, buf)
-    _, rec = tx.pull_parallel(grid, dev)
-    rows.append({"kind": "dpu_cpu_parallel", "banks": n, "nbytes": rec.nbytes,
-                 "gbps": rec.bandwidth / 1e9})
     return rows

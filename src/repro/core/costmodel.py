@@ -22,8 +22,7 @@ per op, bandwidth constants with fixed setup overheads, H2D/D2H asymmetry):
 * :class:`EnergyModel` prices the same profile in joules following the
   per-op/per-access energy accounting of arXiv:2110.01709.
 * :func:`roofline_rows` emits per-workload analytical roofline rows
-  (operational intensity vs compute/transfer roofs) consumed by
-  ``benchmarks/roofline.py`` and the ``cost_model`` bench object.
+  (operational intensity vs compute/transfer roofs).
 
 The fit layer (:meth:`CostModel.fit`) is pure — it consumes measurement
 rows, so tests can feed synthetic sweeps and assert determinism — while
@@ -646,8 +645,7 @@ class CostModel:
 
 
 def roofline_rows(model: CostModel, profiles) -> list:
-    """Per-workload analytical roofline rows (rendered by
-    benchmarks/roofline.py and embedded in the bench cost_model object).
+    """Per-workload analytical roofline rows.
 
     The compute roof is the fitted per-op rate at the profile's op mix;
     the transfer roof is operational intensity times the push/pull mixed

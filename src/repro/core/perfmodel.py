@@ -9,7 +9,7 @@ Two machine models live here:
      Eq. 4  MRAM bandwidth         BW = size * f / L    [B/s]
    with the paper's measured constants (350 MHz, alpha_read=77, alpha_write=61,
    beta=0.5 cyc/B) as defaults.  The model reproduces the paper's Figs. 4-9
-   analytically and is validated against them in tests/benchmarks.
+   analytically and is validated against them in tests/test_perfmodel.py.
 
 2. ``TpuModel`` — the TPU v5e single-chip + mesh model used for the roofline
    analysis of the compiled dry-run artifacts:

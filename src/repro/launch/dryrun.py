@@ -12,7 +12,7 @@ hardware:   jax.jit(step, in_shardings, out_shardings).lower(*specs)
             (FLOPs/bytes) + collective bytes parsed from the optimized HLO.
 
 Results are written as JSON records under ``experiments/dryrun/`` and are the
-single source for EXPERIMENTS.md §Dry-run and §Roofline.
+single source for EXPERIMENTS.md §Dry-run.
 
 Usage:
   python -m repro.launch.dryrun --arch tinyllama-1.1b --shape train_4k

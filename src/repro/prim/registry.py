@@ -14,13 +14,12 @@ One :class:`WorkloadEntry` per paper workload module (Table 2), carrying:
   frontier unions) feeds every bank's next step, so chunks are never
   independent (paper §4.8/§4.10, Key Obs. 16).  The runtime falls back to
   ``pim()`` for them instead of silently skipping;
-* ``make_args`` — the canonical argument generator shared by benchmarks,
-  examples, and the equivalence tests (``make_args(rng, scale)``);
+* ``make_args`` — the canonical argument generator shared by the
+  examples and the equivalence tests (``make_args(rng, scale)``);
 * ``compare`` — the equivalence assertion for this workload's output type
   (exact ints, toleranced floats, TS's (min, argmin) tuple).
 
-Consumed by ``runtime/scheduler.py``, ``benchmarks/throughput.py``,
-``benchmarks/prim_scaling.py``, ``examples/serve_prim.py``, and
+Consumed by ``runtime/scheduler.py``, ``examples/serve_prim.py``, and
 ``examples/prim_suite.py`` — replacing the hand-maintained ``ALL`` dict and
 per-benchmark workload lists.  ``python -m repro.prim.registry`` prints the
 markdown table embedded in README.md (checked by ``tools/check_docs.py``).

@@ -34,21 +34,20 @@ Calibration is two layers, both on the current backend:
 * machine level — ``core.characterize.push_pull_sweep`` /
   ``bank_compute_sweep`` give (nbytes, seconds) points per stage;
   ``core.perfmodel.fit_affine`` recovers (alpha, bw).  These are the
-  backend's Fig. 4/10 analogues, reported in every bench artifact.
+  backend's Fig. 4/10 analogues.
 * workload level — each entry's *chunked* phase callables are timed
   directly (scatter / compute / retrieve, synced at each boundary) at two
   chunk counts, giving an exact per-stage affine fit in the jit-cached
   regime the pipeline actually runs in; the serialized ``pim()`` total
   (second run — the first pays compilation) is kept as the measured
-  baseline, so the plan's predicted overlap and telemetry's achieved
-  ``overlap_speedup`` are the same quantity.
+  baseline the plan's predicted overlap is quoted against.
 
 The model proposes; measurement disposes: ``probe_plan`` re-measures the top
 model candidates (always including the untuned default) and adopts the
 measured-best chunk count — the ATLAS/AutoTVM discipline, and what makes
 "tuned beats or ties the fixed default" hold by construction.
-``runtime/telemetry.py`` records predicted-vs-achieved overlap per request
-so mispredictions stay visible in every bench artifact.
+``runtime/telemetry.py`` stamps every tuned request with its plan's
+``predicted_overlap``.
 
 Rank dimension (DESIGN.md §10).  On a :class:`~repro.core.banked.RankGrid`
 every plan additionally carries ``n_ranks`` — how many ranks the pipeline
@@ -274,7 +273,7 @@ class TunedPlan:
 @dataclasses.dataclass
 class TuningResult:
     """Machine-level stage fits + per-workload profiles and plans, JSON
-    round-trippable (embedded verbatim in BENCH_*.json artifacts).
+    round-trippable.
     ``rank_sweep`` carries the per-rank transfer characterization rows
     (``core.characterize.rank_parallel_sweep``) on a RankGrid, [] on a
     flat grid."""

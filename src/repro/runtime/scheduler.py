@@ -11,8 +11,8 @@ weight); the scheduler owns the grid and decides execution order:
   virtual time serves next, so service share converges to the weight
   ratio under saturation (``policy="qos"``; ``policy="fifo"`` ignores
   tenants/priorities/deadlines and serves global submission order — the
-  baseline the deadline-miss comparison in ``tests/test_serving.py`` and
-  ``benchmarks/loadgen.py`` measures against);
+  baseline the deadline-miss comparison in ``tests/test_serving.py``
+  measures against);
 * **priority + EDF within a tenant** — higher priority first, ties by
   earliest deadline, then FIFO; requests whose deadline passed before
   dispatch are dropped at pop time with a counted ``expired`` outcome

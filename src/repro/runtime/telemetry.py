@@ -2,9 +2,7 @@
 
 Extends the paper's ``PhaseTimes`` stacked-bar accounting (CPU-DPU / DPU /
 Inter-DPU / DPU-CPU) with what a *runtime* needs on top of a benchmark:
-queue wait, per-request latency, overlap speedup against the serialized
-baseline, and achieved CPU↔bank bandwidth.  Benchmarks render both views —
-the paper's serialized bars and the pipelined bars — from the same records.
+queue wait, per-request latency and achieved CPU↔bank bandwidth.
 
 Phase accounting under overlap is host-observed: ``cpu_dpu`` is time spent
 issuing scatters, ``dpu`` time spent enqueueing bank-local compute (the
@@ -96,12 +94,10 @@ class RequestRecord:
     #: (split, scatter, launch, device_wait, copy_out, merge), less
     #: ``device_wait_s``: the host's own time on the request
     host_self_s: float = 0.0
-    serialized_s: float = 0.0   # optional: measured pim() baseline time
     predicted_overlap: float = 0.0   # autotune plan's promise (0 = untuned)
     #: cost-model per-stage seconds (cpu_dpu/dpu/dpu_cpu) stamped from the
-    #: plan's ``predicted_stage_s`` (DESIGN.md §15) — compared against
-    #: ``phases`` so every bench artifact doubles as a model validation
-    #: set; {} when the plan carries no model predictions
+    #: plan's ``predicted_stage_s`` (DESIGN.md §15), comparable with
+    #: ``phases``; {} when the plan carries no model predictions
     predicted_stage_s: dict = dataclasses.field(default_factory=dict)
     tuned: bool = False              # served under a TunedPlan?
     cache_hit: bool = False          # resident operand served warm? (§12)
@@ -120,24 +116,6 @@ class RequestRecord:
     @property
     def latency_s(self) -> float:
         return max(0.0, self.t_finish - self.t_submit)
-
-    @property
-    def overlap_speedup(self) -> float:
-        """Serialized-baseline time over pipelined service time (>1 ⇒ the
-        overlap recovered transfer time the SDK would have serialized)."""
-        if self.serialized_s and self.service_s:
-            return self.serialized_s / self.service_s
-        return 0.0
-
-    @property
-    def overlap_misprediction(self) -> float:
-        """predicted/achieved − 1: positive ⇒ the autotune model
-        over-promised, negative ⇒ it under-promised; 0.0 when either side is
-        missing.  Surfaced per request so a drifting fit is visible in every
-        bench artifact instead of silently mis-tuning (DESIGN.md §8)."""
-        if self.predicted_overlap and self.overlap_speedup:
-            return self.predicted_overlap / self.overlap_speedup - 1.0
-        return 0.0
 
     @property
     def achieved_gbps(self) -> float:
@@ -159,10 +137,8 @@ class RequestRecord:
                 "dpu_cpu_s": self.phases.dpu_cpu,
                 "device_wait_s": self.device_wait_s,
                 "host_self_s": self.host_self_s,
-                "overlap_speedup": self.overlap_speedup,
                 "tuned": self.tuned, "cache_hit": self.cache_hit,
                 "predicted_overlap": self.predicted_overlap,
-                "overlap_misprediction": self.overlap_misprediction,
                 "achieved_gbps": self.achieved_gbps,
                 **{f"predicted_{k}_s": v
                    for k, v in self.predicted_stage_s.items()},
@@ -260,10 +236,6 @@ class Telemetry:
         self._max_latency = 0.0
         self._t_first_submit = float("inf")
         self._t_last_finish = 0.0
-        self._sum_speedup = 0.0
-        self._n_speedup = 0
-        self._sum_mispred = 0.0
-        self._n_mispred = 0
         self._stage_s = dict.fromkeys(_STAGE_KEYS, 0.0)
         self._by_workload: dict[str, _WorkloadStats] = {}
         self._by_tenant: dict[str, _TenantStats] = {}
@@ -289,12 +261,6 @@ class Telemetry:
             self._max_latency = max(self._max_latency, lat)
             self._t_first_submit = min(self._t_first_submit, rec.t_submit)
             self._t_last_finish = max(self._t_last_finish, rec.t_finish)
-            if rec.overlap_speedup > 0:
-                self._sum_speedup += rec.overlap_speedup
-                self._n_speedup += 1
-            if rec.predicted_overlap and rec.overlap_speedup:
-                self._sum_mispred += rec.overlap_misprediction
-                self._n_mispred += 1
             for key in _STAGE_KEYS:
                 self._stage_s[key] += getattr(rec.phases, key)
             self._by_workload.setdefault(
@@ -356,15 +322,10 @@ class Telemetry:
             "max_latency_s": self._max_latency,
             "bytes_moved": self._bytes_moved,
             "aggregate_gbps": self._bytes_moved / wall / 1e9,
-            "mean_overlap_speedup": (self._sum_speedup / self._n_speedup
-                                     if self._n_speedup else 0.0),
             "tuned_requests": self._tuned,
             "cache_hits": self._cache_hits,
             "shed": self._shed,
             "expired": self._expired,
-            "mean_overlap_misprediction": (
-                self._sum_mispred / self._n_mispred
-                if self._n_mispred else 0.0),
             "stage_seconds": {f"{k}_s": v
                               for k, v in self._stage_s.items()},
             "workloads": {name: ws.row()

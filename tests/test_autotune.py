@@ -159,24 +159,6 @@ def test_run_pipelined_stamps_plan_on_record(bank_grid, rng):
     assert rec.tuned and rec.predicted_overlap == 2.0
 
 
-def test_misprediction_metric(bank_grid, rng):
-    from repro import pim
-    e = pim.registry()["VA"]
-    args = e.make_args(rng, 1)
-    plan = TunedPlan(workload="VA", n_chunks=1, max_batch_requests=8,
-                     predicted_serialized_s=1.0, predicted_pipelined_s=0.5,
-                     predicted_overlap=2.0)
-    sched = pim.PimSession(grid=bank_grid, plans={"VA": plan}).scheduler
-    req = sched.submit("VA", *args)
-    sched.drain()
-    rec = req.record
-    rec.serialized_s = 4.0 * rec.service_s          # achieved overlap = 4x
-    assert rec.overlap_speedup == pytest.approx(4.0)
-    # model promised 2x, got 4x: under-promised by half
-    assert rec.overlap_misprediction == pytest.approx(-0.5)
-    assert rec.row(bank_grid.n_banks)["predicted_overlap"] == 2.0
-
-
 # -- 8 simulated banks: tuned beats or ties the fixed default -----------------
 
 SCRIPT = r"""

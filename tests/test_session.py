@@ -201,12 +201,13 @@ def test_plans_accessor_and_tuned_serving(bank_grid, rng):
     np.testing.assert_array_equal(s.run("VA", a, a), a + a)
     (rec,) = s.telemetry.records
     assert rec.tuned and rec.n_chunks == 2 and rec.predicted_overlap == 2.0
+    assert rec.row(bank_grid.n_banks)["predicted_overlap"] == 2.0
     s.close()
 
 
 def test_session_accepts_tuning_result(bank_grid, rng):
-    """plans= takes a whole TuningResult (e.g. restored from a BENCH
-    artifact) and keeps it inspectable via s.tuning."""
+    """plans= takes a whole TuningResult and keeps it inspectable via
+    s.tuning."""
     from repro.runtime import TuningResult
     plan = TunedPlan(workload="VA", n_chunks=3, max_batch_requests=8,
                      predicted_serialized_s=1.0, predicted_pipelined_s=0.5,

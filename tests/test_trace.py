@@ -12,6 +12,7 @@ import time
 
 import pytest
 
+from repro.prim.registry import PIPELINEABLE
 from repro.runtime.trace import (NULL_SPAN, NULL_TRACER, Span, Tracer,
                                  get_tracer, set_tracer)
 
@@ -351,7 +352,7 @@ def _profiled_lines(path) -> list:
     return lines
 
 
-@pytest.mark.parametrize("workload", ["GEMV", "VA"])
+@pytest.mark.parametrize("workload", PIPELINEABLE)
 def test_profiler_spans_of_one_served_request(bank_grid, rng, tmp_path,
                                               workload):
     """Under a profiler session alone (no tracer), one request served by the

@@ -2,9 +2,8 @@
 """Docs-consistency check (CI gate).
 
 Fails if:
-  * any `DESIGN.md §<sec>` / `EXPERIMENTS.md §<sec>` reference in `src/`,
-    `tools/`, or `benchmarks/` cites a file or section heading that does
-    not exist (continuations like "EXPERIMENTS.md §Dry-run and §Roofline"
+  * any `DESIGN.md §<sec>` / `EXPERIMENTS.md §<sec>` reference in `src/`
+    or `tools/` cites a file or section heading that does not exist (continuations like "EXPERIMENTS.md §Tracing and §Decode"
     count, and the § may land on the next line of a wrapped docstring);
   * any file mentioning DESIGN.md / EXPERIMENTS.md exists while the cited
     doc is missing from the repo root;
@@ -51,7 +50,7 @@ def check_ref(doc: str, sec: str, where: str) -> None:
 
 
 def scan_sources() -> None:
-    for tree in ("src", "tools", "benchmarks"):
+    for tree in ("src", "tools"):
         for py in sorted((ROOT / tree).rglob("*.py")):
             text = py.read_text()
             rel = py.relative_to(ROOT)
